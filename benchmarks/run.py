@@ -2,6 +2,7 @@
 
     PYTHONPATH=src python -m benchmarks.run [--only fig2,table2] [--fast]
                                             [--out BENCH_results.json]
+                                            [--chips N]
 
 Every bench returns a structured record (benchmarks.common.bench_record);
 the harness mirrors each to experiments/bench/<name>.json and writes the
@@ -62,6 +63,9 @@ FAST_KW = {
                      sessions_sweep=(2,), overhead_bound=4.0),
 }
 
+# benches whose engine can shard its lanes over a mesh of --chips devices
+MESH_BENCHES = ("serving_session", "serving_service")
+
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
@@ -70,13 +74,18 @@ def main(argv=None):
     ap.add_argument("--out", default=None,
                     help="aggregate report path (default: BENCH_results.json"
                          " at the repo root)")
+    ap.add_argument("--chips", type=int, default=1,
+                    help="devices the serving benches shard their lanes "
+                         "over (default 1: unsharded)")
     args = ap.parse_args(argv)
     names = args.only.split(",") if args.only else list(BENCHES)
 
     records, failed = {}, []
     for name in names:
         fn = BENCHES[name]
-        kw = FAST_KW.get(name, {}) if args.fast else {}
+        kw = dict(FAST_KW.get(name, {})) if args.fast else {}
+        if name in MESH_BENCHES:
+            kw["chips"] = args.chips
         print(f"\n##### bench: {name} #####", flush=True)
         t0 = time.time()
         try:
@@ -113,4 +122,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.core import compilemon
+    compilemon.use_persistent_cache()
     sys.exit(main())

@@ -69,6 +69,7 @@ from benchmarks.common import (RESULTS_DIR, bench_record, print_table,
 from repro import obs as obs_lib
 from repro.apps import histo
 from repro.core import compilemon
+from repro.core.distributed import lane_mesh
 from repro.data.pipeline import ArrayRecordCorpus, write_corpus
 from repro.data.zipf import zipf_tuples
 from repro.obs import parse_prometheus
@@ -99,18 +100,14 @@ def _phase_windows(tenants: int, appends_per_tenant: int):
 
 
 def run(tenants: int = 2048, appends_per_tenant: int = 2, chunk: int = 64,
-        num_pri: int = 8, conns: int = 64, mesh="auto", aot_buckets: int = 2,
+        num_pri: int = 8, conns: int = 64, chips: int = 1, aot_buckets: int = 2,
         coalesce_max: int = 256, corpus_path: Optional[str] = None,
         export_dir: Optional[str] = None, seed: int = 23,
         obs_overhead_bound: float = 5.0):
-    import jax
-    if mesh == "auto":
-        mesh = (jax.make_mesh((len(jax.devices()),), ("lanes",))
-                if len(jax.devices()) > 1 else None)
     primary_slots = tenants
+    mesh = lane_mesh(chips)
     if mesh is not None:
-        num_dev = dict(mesh.shape)["lanes"]
-        primary_slots += -primary_slots % num_dev
+        primary_slots += -primary_slots % chips
     spec = histo.make_spec(BINS, DOMAIN, num_pri)
     # a deep trace ring: the per-op root-span-count asserts below need
     # EVERY request's span tree retained (zero drops)
@@ -481,8 +478,10 @@ if __name__ == "__main__":
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke: 1024 tenants, short windows")
     ap.add_argument("--tenants", type=int, default=None)
+    ap.add_argument("--chips", type=int, default=1,
+                    help="shard the lanes over this many devices")
     args = ap.parse_args()
-    kw = {}
+    kw = {"chips": args.chips}
     if args.fast:
         kw.update(tenants=1024, appends_per_tenant=2)
     if args.tenants is not None:

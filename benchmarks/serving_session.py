@@ -75,6 +75,7 @@ from benchmarks.common import (RESULTS_DIR, bench_record, print_table,
 from repro import obs as obs_lib
 from repro.apps import histo
 from repro.core import compilemon
+from repro.core.distributed import lane_mesh
 from repro.data.zipf import zipf_tuples
 from repro.obs import parse_prometheus, report as obs_report
 from repro.serve import DurableSessionEngine, SessionEngine
@@ -85,22 +86,18 @@ HOT_TENANT = 3            # the alpha=2.0 tenant appends hot_factor x data
 
 def run(n_tuples: int = 1 << 15, rounds: int = 5, chunk: int = 2048,
         num_pri: int = 16, num_sec: int = 8, primary_slots: int = 4,
-        secondary_slots: int = 2, hot_factor: int = 4, mesh="auto",
+        secondary_slots: int = 2, hot_factor: int = 4, chips: int = 1,
         aot_buckets: int = 8, storm_sessions: int = 1024,
         storms: int = 3, storm_chunk: int = 256,
         obs_overhead_bound: float = 5.0,
         export_dir: Optional[str] = None):
-    import jax
     if rounds < 3:
         raise ValueError("rounds must be >= 3: one warm-up pass plus at "
                          "least one timed round per flush tier")
-    if mesh == "auto":
-        mesh = (jax.make_mesh((len(jax.devices()),), ("lanes",))
-                if len(jax.devices()) > 1 else None)
+    mesh = lane_mesh(chips)
     if mesh is not None:
         # shard_map splits the lanes axis evenly: pad primary slots up
-        num_dev = dict(mesh.shape)["lanes"]
-        primary_slots += -(primary_slots + secondary_slots) % num_dev
+        primary_slots += -(primary_slots + secondary_slots) % chips
     spec = histo.make_spec(512, 1 << 20, num_pri)
     # one shared bundle across both phases: the serving engine and the
     # storm engine emit into the same registry/trace, so the exports
@@ -267,7 +264,7 @@ def run(n_tuples: int = 1 << 15, rounds: int = 5, chunk: int = 2048,
     # storm re-admits a cold full house through the same buckets.
     storm_num_pri = 8
     if mesh is not None:
-        storm_sessions += -storm_sessions % num_dev
+        storm_sessions += -storm_sessions % chips
     storm_spec = histo.make_spec(512, 1 << 20, storm_num_pri)
     storm_aot = 2 if aot_buckets is not None else None
     # durable on purpose: open_batch dispatches through the virtual

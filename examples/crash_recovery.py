@@ -2,7 +2,7 @@
 recover it, and verify every answer against the uninterrupted oracle
 (DESIGN.md §10, docs/durability.md).
 
-    PYTHONPATH=src python examples/crash_recovery.py [workdir]
+    PYTHONPATH=src python examples/crash_recovery.py [--chips N] [workdir]
 
 The script is its own harness: the parent re-runs this file with
 ``--child``, and the CHILD process drives a ``serve.DurableSessionEngine``
@@ -20,11 +20,14 @@ process death with un-checkpointed WAL tail on disk.  The parent then
   4. keeps streaming post-recovery and closes every session, again
      oracle-exact.
 
-Multi-device: under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-(N in {2,4,8}; CI uses 4) both processes run the engine with the slot
-lanes sharded over a ``lanes`` mesh axis, so the recovery restores
-through the ``executor.put_lanes`` + lane-sharding path.
+Multi-device: with ``--chips N`` (N in {2,4,8}; on CPU under
+``XLA_FLAGS=--xla_force_host_platform_device_count=N``, CI uses 4) both
+processes run the engine with the slot lanes sharded over a ``lanes``
+mesh axis of N devices, so the recovery restores through the
+``executor.put_lanes`` + lane-sharding path.  The parent touches JAX
+only after the child is gone: one process at a time holds a chip.
 """
+import argparse
 import os
 import signal
 import subprocess
@@ -48,13 +51,11 @@ def batch(r: int, t: int) -> np.ndarray:
     return zipf_tuples(n, DOMAIN, 1.5, seed=1000 * r + t)
 
 
-def make_engine(dirpath: str, recovering: bool):
-    import jax
-
+def make_engine(dirpath: str, recovering: bool, chips: int):
     from repro.apps import histo
+    from repro.core.distributed import lane_mesh
     from repro.serve import DurableSessionEngine, SessionEngine
-    mesh = (jax.make_mesh((len(jax.devices()),), ("lanes",))
-            if len(jax.devices()) > 1 else None)
+    mesh = lane_mesh(chips)
     spec = histo.make_spec(BINS, DOMAIN, NUM_PRI)
     if recovering:
         return spec, SessionEngine.recover(spec, dirpath, mesh=mesh)
@@ -64,8 +65,8 @@ def make_engine(dirpath: str, recovering: bool):
         secondary_slots=SECONDARY_SLOTS, checkpoint_every=2, mesh=mesh)
 
 
-def child(dirpath: str):
-    _, eng = make_engine(dirpath, recovering=False)
+def child(dirpath: str, chips: int):
+    _, eng = make_engine(dirpath, recovering=False, chips=chips)
     sids = {t: eng.open(f"t{t}") for t in range(TENANTS)}
     for r in range(PRE_ROUNDS):
         for t in sids:
@@ -77,18 +78,18 @@ def child(dirpath: str):
     os.kill(os.getpid(), signal.SIGKILL)     # mid-stream, no cleanup
 
 
-def main():
-    workdir = (sys.argv[1] if len(sys.argv) > 1
-               else tempfile.mkdtemp(prefix="crash_recovery_"))
+def main(workdir, chips: int):
+    workdir = workdir or tempfile.mkdtemp(prefix="crash_recovery_")
     r = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--child", workdir],
+        [sys.executable, os.path.abspath(__file__), "--child",
+         "--chips", str(chips), workdir],
         env=os.environ.copy(), timeout=560)
     assert r.returncode == -signal.SIGKILL, \
         f"child exited {r.returncode}, expected SIGKILL"
     print("OK child SIGKILLed mid-stream")
 
     from repro.apps import histo
-    spec, eng = make_engine(workdir, recovering=True)
+    spec, eng = make_engine(workdir, recovering=True, chips=chips)
     if eng._sharded is not None:
         print(f"recovering across {eng.num_lanes // eng.lanes_per_device} "
               f"devices x {eng.lanes_per_device} lanes")
@@ -130,7 +131,13 @@ def main():
 
 
 if __name__ == "__main__":
-    if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        child(sys.argv[2])
+    ap = argparse.ArgumentParser()
+    ap.add_argument("workdir", nargs="?", default=None)
+    ap.add_argument("--chips", type=int, default=1,
+                    help="shard the lanes over this many devices")
+    ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
+    args = ap.parse_args()
+    if args.child:
+        child(args.workdir, args.chips)
     else:
-        main()
+        main(args.workdir, args.chips)

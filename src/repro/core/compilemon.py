@@ -58,7 +58,9 @@ Interleaving contract (pinned by ``tests/test_obs.py``)
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
+from pathlib import Path
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _STALL_EVENTS = (
@@ -66,6 +68,11 @@ _STALL_EVENTS = (
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
     "/jax/core/compile/backend_compile_duration",
 )
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# fixed, git-ignored and inside the checkout: the cache key includes the
+# path, so a directory that moved between runs would never hit
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 _lock = threading.Lock()
 _installed = False
@@ -125,3 +132,18 @@ def since(before: CompileSnapshot) -> CompileDelta:
     return CompileDelta(
         n_compiles=now.n_compiles - before.n_compiles,
         stall_ms=round((now.stall_secs - before.stall_secs) * 1e3, 3))
+
+
+def use_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point
+    (``chip_smoke.py``, ``python -m benchmarks.run``) and return its
+    directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and this sets no other; otherwise the cache goes to
+    ``DEFAULT_CACHE_DIR``.  The test suite never calls this: the cache
+    stays off there."""
+    path = os.environ.get(CACHE_ENV)
+    if not path:
+        import jax
+        path = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

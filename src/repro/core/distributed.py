@@ -42,12 +42,11 @@ devices).  Full mapping table + worked example: docs/distributed.md.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import executor as core_executor
@@ -56,19 +55,27 @@ from repro.core import scheduler as core_scheduler
 from repro.core.types import DittoSpec, RoutePlan
 
 
-def _shard_map():
-    """jax.shard_map only exists from jax 0.6; fall back to the
-    experimental home it had before that."""
-    try:
-        return jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as _sm
+def lane_mesh(chips: int):
+    """The ``lanes`` mesh over the first ``chips`` devices, for
+    ``serve.SessionEngine(mesh=...)``; ``None`` (the unsharded engine)
+    for one chip.  Entry points take the chip count as an argument: it
+    is never inferred from how many devices happen to be visible."""
+    devices = jax.devices()
+    if not 1 <= chips <= len(devices):
+        raise ValueError(f"chips={chips}: JAX sees {len(devices)} device(s)")
+    if chips == 1:
+        return None
+    return jax.make_mesh((chips,), ("lanes",), devices=devices[:chips])
 
-        def shard_map(f, mesh, in_specs, out_specs):
-            return _sm(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
 
-        return shard_map
+def _auto_axes(mesh):
+    """``mesh`` with every axis of type Auto.  Both lifts index sharded
+    arrays with plain gathers and scatters (``executor.take_lanes`` /
+    ``put_lanes``, the merger loop of ``run_stream``), which needs the
+    compiler to propagate shardings; ``jax.make_mesh`` makes Explicit
+    axes, under which such a gather is refused."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_distributed_executor(spec: DittoSpec, mesh, num_pri: int,
@@ -83,6 +90,7 @@ def make_distributed_executor(spec: DittoSpec, mesh, num_pri: int,
     [P, *local] sharded over `axis`.  ``capacity`` is the per-(producer,
     destination) all_to_all budget -- tuples beyond it drop (counted).
     """
+    mesh = _auto_axes(mesh)
     num_pe = dict(mesh.shape)[axis]          # physical shards
     assert num_pri + num_sec <= num_pe
 
@@ -132,9 +140,8 @@ def make_distributed_executor(spec: DittoSpec, mesh, num_pri: int,
         workload = jax.lax.psum(workload, axis)              # global hist
         return (new_buf[None], my_load[None], dropped[None], workload)
 
-    shard_map = _shard_map()
     pspec = P(axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(pspec, pspec, P(), P()),
         out_specs=(pspec, pspec, pspec, P())))
@@ -247,6 +254,18 @@ class ShardedLaneExecutor:
         host-side or cross-shard edit, e.g. ``executor.put_lanes``)."""
         return jax.device_put(states, self.lane_sharding)
 
+    def to_group_device(self, tree):
+        """Move a gathered lane group (``executor.take_lanes``) onto the
+        mesh's first device, where the per-session tier scans it: a
+        Pallas kernel cannot be partitioned automatically, so a program
+        that holds one runs on one device or under ``shard_map``."""
+        return jax.device_put(tree, self.mesh.devices.flat[0])
+
+    def replicate(self, tree):
+        """Replicate a lane group over the mesh, ready to be scattered
+        back into the lane-sharded state (``executor.put_lanes``)."""
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
+
 
 def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
                                num_lanes: int, *,
@@ -260,7 +279,7 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
             f"'{axis}' axis size {num_dev} (shard_map splits the lanes "
             "axis evenly); pad primary/secondary slots up")
     lanes_per_device = num_lanes // num_dev
-    shard_map = _shard_map()
+    mesh = _auto_axes(mesh)
     pspec = P(axis)
     sharding = NamedSharding(mesh, pspec)
     fresh = res.init_state()
@@ -302,7 +321,7 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
     def _run(states, chunks, mask):
         return jax.vmap(res.scan_chunks)(states, chunks, mask)
 
-    run_lanes = jax.jit(shard_map(
+    run_lanes = jax.jit(jax.shard_map(
         _run, mesh=mesh, in_specs=(pspec, pspec, pspec),
         out_specs=(pspec, pspec)))
 
@@ -310,13 +329,13 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
         picked = merge_selected(states, local_ids() == i)
         return jax.tree.map(lambda x: jax.lax.psum(x, axis), picked)
 
-    merge_lane = jax.jit(shard_map(
+    merge_lane = jax.jit(jax.shard_map(
         _merge, mesh=mesh, in_specs=(pspec, P()), out_specs=P()))
 
     def _reset(states, i):
         return set_lane(states, local_ids() == i, fresh)
 
-    reset_lane = jax.jit(shard_map(
+    reset_lane = jax.jit(jax.shard_map(
         _reset, mesh=mesh, in_specs=(pspec, P()), out_specs=pspec))
 
     fold_lane = None
@@ -339,7 +358,7 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
             states = dataclasses.replace(states, buffers=bufs)
             return set_lane(states, gid == src, fresh)
 
-        fold_lane = jax.jit(shard_map(
+        fold_lane = jax.jit(jax.shard_map(
             _fold, mesh=mesh, in_specs=(pspec, P(), P()), out_specs=pspec))
 
     return ShardedLaneExecutor(

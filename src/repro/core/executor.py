@@ -48,8 +48,8 @@ Array = jax.Array
 def default_pe_update(buffers: Array, eff: Array, idx: Array, value: Array,
                       combine: str, backend: Optional[str] = None) -> Array:
     """PriPE/SecPE buffer update, routed through the kernel backend
-    dispatcher: jnp scatter on CPU, the route_accumulate one-hot MXU kernel
-    on TPU/GPU (kernels/dispatch.pe_buffer_update)."""
+    dispatcher: jnp scatter on CPU, the route_accumulate one-hot kernel on
+    TPU/GPU (kernels/dispatch.pe_buffer_update)."""
     return K.pe_buffer_update(buffers, eff, idx, value, combine,
                               backend=backend)
 

@@ -43,7 +43,11 @@ def apply_schedule(plan: RoutePlan, assignment: jax.Array) -> RoutePlan:
     fresh = init_plan(num_pri, plan.num_sec)
     if plan.num_sec == 0:
         return fresh
-    table, counter = fresh.table, fresh.counter
+    # the carry starts *_like(assignment) so that, under shard_map, it
+    # varies over the same mesh axes as the plan the loop writes into it
+    table = fresh.table + jnp.zeros_like(assignment, jnp.int32,
+                                         shape=fresh.table.shape)
+    counter = jnp.ones_like(assignment, jnp.int32, shape=fresh.counter.shape)
 
     def body(j, carry):
         table, counter = carry

@@ -38,8 +38,11 @@ def schedule_secpes(workload: jax.Array, num_sec: int, *,
     if num_sec == 0:
         return jnp.zeros((0,), jnp.int32)
     w = workload.astype(jnp.float32)
-    shares = jnp.ones((m,), dtype=jnp.float32)  # 1 + #SecPEs attached
-    assignment = jnp.full((num_sec,), -1, dtype=jnp.int32)
+    # the carry is built *_like(w) so that it varies over the same mesh
+    # axes as the workload when traced under shard_map: a constant carry
+    # would change type in the loop body
+    shares = jnp.ones_like(w)                   # 1 + #SecPEs attached
+    assignment = jnp.full_like(w, -1, shape=(num_sec,), dtype=jnp.int32)
 
     def body(j, carry):
         shares, assignment = carry

@@ -1,7 +1,7 @@
 """Pallas TPU kernels for the compute hot-spots (see DESIGN.md §2):
 
-  route_accumulate -- PE buffer scatter-accumulate as one-hot MXU matmul
-  cms_update       -- count-min sketch multi-row update
+  route_accumulate -- PE buffer scatter-accumulate as a one-hot VPU reduce
+  cms_update       -- count-min sketch multi-row update (one-hot MXU)
   moe_onehot       -- dispatch/combine one-hot contractions (routing network)
   flash_attention  -- online-softmax attention fwd (LM prefill hot-spot)
 

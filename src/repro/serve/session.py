@@ -745,7 +745,7 @@ class SessionEngine:
                 row_sessions = [s] * n_real_lanes + \
                     [None] * (len(lanes) - n_real_lanes)
                 idx = np.asarray(lanes, np.int32)
-                sub = self._take_lanes(self._states, idx)
+                sub = self._gather_group(self._states, idx)
                 segs = list(self._segments(group_chunks))
                 with self._segment_loop_span(segs, "session") as seg_span:
                     for off, w in segs:
@@ -761,9 +761,7 @@ class SessionEngine:
                                 [min(max(len(c) - off, 0), w)
                                  for c in group_chunks])
                         width += w
-                states = self._put_lanes(self._states, idx, sub)
-                self._states = (states if self._sharded is None
-                                else self._sharded.shard_states(states))
+                self._states = self._scatter_group(self._states, idx, sub)
             sp.set(tuples=n_real, width=width)
         self._record_flush(n_real, group_chunks, width, scope="session",
                            snap=snap, ms=(time.perf_counter() - t0) * 1e3)
@@ -822,7 +820,7 @@ class SessionEngine:
             group_masks += [[] for _ in pads]
         row_sessions = live + [None] * (len(lanes) - n_real_lanes)
         idx = np.asarray(lanes, np.int32)
-        sub = self._take_lanes(self._states, idx)
+        sub = self._gather_group(self._states, idx)
         width = n_disp = 0
         for off, w in self._segments(group_chunks):
             with self.obs.span("scan.segment", cat="scan", scope="admit",
@@ -836,9 +834,7 @@ class SessionEngine:
                     [min(max(len(c) - off, 0), w) for c in group_chunks])
             width += w
             n_disp += 1
-        states = self._put_lanes(self._states, idx, sub)
-        self._states = (states if self._sharded is None
-                        else self._sharded.shard_states(states))
+        self._states = self._scatter_group(self._states, idx, sub)
         return group_chunks, width, flushed, n_disp
 
     # ------------------------------------------------------- AOT bucket table
@@ -966,14 +962,12 @@ class SessionEngine:
         # families share the ("grp", b, w) table
         for b in sorted({*self._group_buckets, *self._admit_buckets}):
             idx = np.arange(b, dtype=np.int32)
-            sub = self._take_lanes(scratch, idx)     # primes the gather
+            sub = self._gather_group(scratch, idx)    # primes the gather
             for w in self._aot_widths:
                 zc, zm = zeros(b, w)
                 self._aot[("grp", b, w)] = \
                     self._run_group.lower(sub, zc, zm).compile()
-            put = self._put_lanes(scratch, idx, sub)  # primes the scatter
-            if self._sharded is not None:
-                self._sharded.shard_states(put)
+            self._scatter_group(scratch, idx, sub)    # primes the scatter
         # batched lane-init shapes: close resets exact group sizes
         # (1..1+secondary_slots); the storm lane-init pads its idx up to
         # the admission bucket
@@ -1001,6 +995,23 @@ class SessionEngine:
             "warmup_compile_ms": float(d.stall_ms),
         }
         return self._aot_info
+
+    def _gather_group(self, states, idx):
+        """Lanes ``idx`` of ``states`` as one stacked ``ExecState`` for
+        the per-session tier.  Under ``mesh=`` the group moves to one
+        device: its scan holds a Pallas kernel, which cannot be
+        partitioned across the mesh automatically."""
+        sub = self._take_lanes(states, idx)
+        return sub if self._sharded is None else \
+            self._sharded.to_group_device(sub)
+
+    def _scatter_group(self, states, idx, sub):
+        """Inverse of ``_gather_group``: ``states`` with lanes ``idx``
+        replaced by ``sub``, re-pinned to the lane sharding."""
+        if self._sharded is None:
+            return self._put_lanes(states, idx, sub)
+        states = self._put_lanes(states, idx, self._sharded.replicate(sub))
+        return self._sharded.shard_states(states)
 
     def _lane_group(self, slot: int) -> List[int]:
         """The lane ids a primary slot currently owns: its primary lane
@@ -1341,6 +1352,12 @@ class SessionEngine:
             occ[s.tenant] = occ.get(s.tenant, 0) + 1
             bl[s.tenant] = bl.get(s.tenant, 0) + int(s.backlog_tuples)
         return occ, bl
+
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of the lanes-stacked executor state the engine keeps on
+        its device(s) -- every lane's PE buffers and carry."""
+        return sum(x.nbytes for x in jax.tree.leaves(self._states))
 
     @property
     def slot_reschedules(self) -> int:

@@ -142,6 +142,16 @@ class TestShardedLaneExecutor:
                      for i in range(self.NUM_LANES))
         assert total == total0
 
+    def test_lane_mesh_takes_an_explicit_chip_count(self):
+        """One chip means the unsharded engine; a count beyond the
+        visible devices is refused instead of silently shrunk."""
+        from repro.core import distributed as D
+        assert D.lane_mesh(1) is None
+        with pytest.raises(ValueError):
+            D.lane_mesh(len(jax.devices()) + 1)
+        with pytest.raises(ValueError):
+            D.lane_mesh(0)
+
     def test_missing_axis_and_lane_split(self, small_spec):
         from repro.core import distributed as D
         from repro.core import executor as E

@@ -444,7 +444,7 @@ def test_crash_recovery_example_sigkill_multi_device(cpu_mesh_env,
     asserted."""
     r = subprocess.run(
         [sys.executable, str(REPO / "examples" / "crash_recovery.py"),
-         str(tmp_path / "durable")],
+         "--chips", "8", str(tmp_path / "durable")],
         env=cpu_mesh_env, capture_output=True, text=True, timeout=560,
         cwd=str(REPO))
     assert r.returncode == 0, r.stdout + r.stderr

@@ -233,6 +233,37 @@ class TestCompileAttribution:
             - a.inclusive.n_compiles - b.inclusive.n_compiles)
 
 
+class TestPersistentCache:
+    """``compilemon.use_persistent_cache``: the entry points' one place
+    for the compile cache.  ``jax.config.update`` is replaced by a
+    recorder, so the suite's own cache stays off."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        import jax
+        seen = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda name, value: seen.append((name, value)))
+        return seen
+
+    def test_env_directory_wins_and_nothing_else_is_set(self, monkeypatch,
+                                                        updates, tmp_path):
+        monkeypatch.setenv(compilemon.CACHE_ENV, str(tmp_path))
+        assert compilemon.use_persistent_cache() == str(tmp_path)
+        assert updates == []
+
+    def test_fixed_ignored_directory_in_the_checkout(self, monkeypatch,
+                                                     updates):
+        from pathlib import Path
+        monkeypatch.delenv(compilemon.CACHE_ENV, raising=False)
+        repo = Path(__file__).resolve().parent.parent
+        path = compilemon.use_persistent_cache()
+        assert path == str(repo / ".jax_cache")
+        assert updates == [("jax_compilation_cache_dir", path)]
+        ignored = (repo / ".gitignore").read_text().splitlines()
+        assert ".jax_cache/" in ignored
+
+
 # ---------------------------------------------------- Observability bundle
 class TestObservabilityBundle:
     def test_resolve(self):
