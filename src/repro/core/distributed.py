@@ -254,12 +254,18 @@ class ShardedLaneExecutor:
         host-side or cross-shard edit, e.g. ``executor.put_lanes``)."""
         return jax.device_put(states, self.lane_sharding)
 
+    @property
+    def group_device(self):
+        """The mesh's first device, where the per-session tier scans a
+        gathered lane group: a Pallas kernel cannot be partitioned
+        automatically, so a program that holds one runs on one device or
+        under ``shard_map``."""
+        return self.mesh.devices.flat[0]
+
     def to_group_device(self, tree):
-        """Move a gathered lane group (``executor.take_lanes``) onto the
-        mesh's first device, where the per-session tier scans it: a
-        Pallas kernel cannot be partitioned automatically, so a program
-        that holds one runs on one device or under ``shard_map``."""
-        return jax.device_put(tree, self.mesh.devices.flat[0])
+        """Move a gathered lane group (``executor.take_lanes``) onto
+        ``group_device``."""
+        return jax.device_put(tree, self.group_device)
 
     def replicate(self, tree):
         """Replicate a lane group over the mesh, ready to be scattered

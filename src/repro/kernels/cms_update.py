@@ -91,6 +91,7 @@ def cms_update(eff: jax.Array, cols: jax.Array, value: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_kernel, depth=depth, block_w=wb, rows=rows),
+        name="cms_update",
         grid=(wp // wb, tp // tt),
         in_specs=[
             pl.BlockSpec((tt, 1), lambda i, j: (j, 0)),

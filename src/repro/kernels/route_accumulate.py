@@ -99,6 +99,7 @@ def route_accumulate(flat_idx: jax.Array, value: jax.Array, num_bins: int,
 
     out = pl.pallas_call(
         functools.partial(_kernel, combine=combine, block_bins=bb),
+        name="route_accumulate",
         grid=(nb // bb, tp // tt),
         in_specs=[
             pl.BlockSpec((tt // 128, 128), lambda i, j: (j, 0)),

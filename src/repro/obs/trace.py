@@ -20,6 +20,17 @@ detail pane).  The event buffer is a ring (``cap`` events, oldest
 dropped first, ``dropped`` counted) so a long-running engine holds a
 bounded trace tail; ``enabled=False`` makes ``span()`` return a shared
 no-op context (one attribute check per call on the disabled path).
+
+A span opened with ``span()`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name (the name only: the
+attributes would cost a formatted string per call), so while a JAX
+profiler trace runs the span lands on the profiler's host plane, on the
+thread that opened it, nested over the XLA host events it covers --
+device idle time can then be put down to a span.  With no profiler
+running an annotation costs about 1 us per enter/exit (an Intel Xeon
+host).  Spans emitted after the fact (``complete``,
+``complete_batch``, ``defer``: the service's ``svc.request`` trees)
+have no live interval to annotate and stay in the JSON ring only.
 """
 from __future__ import annotations
 
@@ -32,6 +43,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -112,9 +125,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span; records a complete ("X") event on exit."""
+    """One live span; records a complete ("X") event on exit and mirrors
+    its interval into the JAX profiler as a ``TraceAnnotation``."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, cat: str,
                  args: Dict[str, Any]):
@@ -129,11 +143,14 @@ class _Span:
         self.args.update(attrs)
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         now = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         self._tracer._emit({
             "name": self.name, "ph": "X", "cat": self.cat,
             "ts": self._t0 // 1000 - self._tracer._epoch_us,
@@ -171,7 +188,9 @@ class SpanTracer:
     def span(self, name: str, cat: str = "engine", **attrs):
         """Context manager timing one span; ``attrs`` become the event's
         ``args``.  Nest freely -- containment on the thread track is the
-        nesting the trace viewer renders."""
+        nesting the trace viewer renders.  The span is also a profiler
+        ``TraceAnnotation`` of ``name`` while it is open; disabled, the
+        shared no-op context is returned and nothing is annotated."""
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, cat, attrs)

@@ -388,7 +388,6 @@ class SessionService:
             max_workers=1, thread_name_prefix="svc-engine")
         self._addr: Optional[Tuple[str, int]] = None
         self._loop_tid = 0
-        self._conn_seq = 0
         self._n_conns = 0
         self._started = False
 
@@ -513,8 +512,6 @@ class SessionService:
 
     async def _handle_conn(self, reader: asyncio.StreamReader,
                            writer: asyncio.StreamWriter) -> None:
-        self._conn_seq += 1
-        cid = self._conn_seq
         self._n_conns += 1
         if self._mx:
             self._mx.conns.set(float(self._n_conns))
@@ -522,42 +519,41 @@ class SessionService:
         decoder = FrameDecoder(self.cfg.max_frame)
         tasks: List[asyncio.Task] = []
         try:
-            with self.obs.span("svc.conn", cat="service", conn=cid):
-                try:
-                    hello = await reader.readexactly(len(MAGIC))
-                except (asyncio.IncompleteReadError, ConnectionError):
+            try:
+                hello = await reader.readexactly(len(MAGIC))
+            except (asyncio.IncompleteReadError, ConnectionError):
+                return
+            if hello != MAGIC:
+                await self._write(writer, wlock, self._err_response(
+                    {}, ProtocolError("bad connection magic")))
+                if self._mx:
+                    self._mx.bad_frames.inc()
+                return
+            async with wlock:
+                writer.write(MAGIC)
+                await writer.drain()
+            while True:
+                data = await reader.read(1 << 16)
+                if not data:
+                    if decoder.buffered and self._mx:
+                        self._mx.truncated.inc()   # died mid-frame
                     return
-                if hello != MAGIC:
-                    await self._write(writer, wlock, self._err_response(
-                        {}, ProtocolError("bad connection magic")))
+                try:
+                    decoder.feed(data)
+                    while True:
+                        msg = decoder.next()
+                        if msg is None:
+                            break
+                        t = asyncio.get_running_loop().create_task(
+                            self._dispatch(msg[0], msg[1], writer, wlock))
+                        tasks.append(t)
+                        tasks = [x for x in tasks if not x.done()]
+                except ProtocolError as e:
                     if self._mx:
                         self._mx.bad_frames.inc()
-                    return
-                async with wlock:
-                    writer.write(MAGIC)
-                    await writer.drain()
-                while True:
-                    data = await reader.read(1 << 16)
-                    if not data:
-                        if decoder.buffered and self._mx:
-                            self._mx.truncated.inc()   # died mid-frame
-                        return
-                    try:
-                        decoder.feed(data)
-                        while True:
-                            msg = decoder.next()
-                            if msg is None:
-                                break
-                            t = asyncio.get_running_loop().create_task(
-                                self._dispatch(msg[0], msg[1], writer, wlock))
-                            tasks.append(t)
-                            tasks = [x for x in tasks if not x.done()]
-                    except ProtocolError as e:
-                        if self._mx:
-                            self._mx.bad_frames.inc()
-                        await self._write(writer, wlock,
-                                          self._err_response({}, e))
-                        return        # no resync point after corruption
+                    await self._write(writer, wlock,
+                                      self._err_response({}, e))
+                    return        # no resync point after corruption
         except ConnectionError:       # client vanished; nothing to answer
             return
         finally:

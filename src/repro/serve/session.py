@@ -105,8 +105,12 @@ Telemetry + observability (DESIGN.md §11, docs/observability.md)
   metrics registry (``flush_latency_ms{scope}``, ``lane_occupancy
   {lane}``, ``secondary_grants_total{tenant}``, ``backlog_depth
   {tenant}``, retrace counters -- Prometheus-exportable) and a span
-  tracer (``engine.flush`` / ``scan.segment`` / ``engine.admit_storm``
-  / ``merge.snapshot`` ... as Perfetto ``trace_event`` JSON).  Pass one
+  tracer (``engine.flush`` / ``scan.segment`` > ``scan.pack`` /
+  ``scan.h2d`` / ``scan.run`` / ``engine.admit_storm`` /
+  ``merge.snapshot`` ... as Perfetto ``trace_event`` JSON, each span
+  also a JAX profiler annotation).  Every flush row carries its scan
+  steps' host times (``pack_ms``, ``h2d_ms``, ``run_ms``, ``segments``)
+  whether tracing is on or off.  Pass one
   ``Observability`` to share a registry across engines, ``obs=False``
   to disable (every op an early return -- the serving bench asserts
   the enabled overhead stays under its bound).
@@ -121,7 +125,6 @@ Durability (DESIGN.md §10, docs/durability.md)
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import heapq
 import time
@@ -179,6 +182,30 @@ class _Session:
         first = self.backlog[0]
         head = first[self.backlog_off:] if self.backlog_off else first
         return [head, *list(self.backlog)[1:]]
+
+
+class _ScanClock:
+    """Host time one flush spent in each step of its scan segments
+    (``SessionEngine._scan_batch``), summed over the segments; becomes
+    the telemetry row's ``pack_ms`` / ``h2d_ms`` / ``run_ms`` /
+    ``segments`` columns."""
+
+    __slots__ = ("pack_ns", "h2d_ns", "run_ns", "segments")
+
+    def __init__(self):
+        self.pack_ns = self.h2d_ns = self.run_ns = self.segments = 0
+
+    def add(self, pack_ns: int, h2d_ns: int, run_ns: int) -> None:
+        self.pack_ns += pack_ns
+        self.h2d_ns += h2d_ns
+        self.run_ns += run_ns
+        self.segments += 1
+
+    def columns(self) -> Dict[str, Any]:
+        return {"pack_ms": round(self.pack_ns / 1e6, 3),
+                "h2d_ms": round(self.h2d_ns / 1e6, 3),
+                "run_ms": round(self.run_ns / 1e6, 3),
+                "segments": self.segments}
 
 
 class _EngineMetrics:
@@ -504,6 +531,7 @@ class SessionEngine:
                     "first-append entries (pass one per tenant, or None)")
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
+        clock = _ScanClock()
         with self.obs.span("engine.admit_storm", cat="admit",
                            n_tenants=len(tenants)) as sp:
             sids: List[int] = []
@@ -514,10 +542,10 @@ class SessionEngine:
                     self.append(sid, first[i])
             admitted = [sid for sid in sids
                         if self.sessions[sid].slot is not None]
-            group_chunks, width, flushed, n_disp = \
-                self._flush_admission(admitted)
+            group_chunks, width, flushed = \
+                self._flush_admission(admitted, clock)
             sp.set(n_admitted=len(admitted),
-                   n_scan_dispatches=int(n_disp))
+                   n_scan_dispatches=clock.segments)
         ms = (time.perf_counter() - t0) * 1e3
         delta = compilemon.since(snap)
         self._storms += 1
@@ -531,8 +559,9 @@ class SessionEngine:
                            snap=snap, ms=ms,
                            extra={"n_admitted": len(admitted),
                                   "n_queued_batch": len(sids) - len(admitted),
-                                  "n_scan_dispatches": int(n_disp),
-                                  "admit_ms": round(ms, 3)})
+                                  "n_scan_dispatches": clock.segments,
+                                  "admit_ms": round(ms, 3),
+                                  **clock.columns()})
         self._flush_no += 1
         return sids
 
@@ -553,11 +582,9 @@ class SessionEngine:
                 f"append shape {data.shape[1:]} != engine tuple "
                 f"shape {self._feat_shape}")
         if len(data):
-            with self.obs.span("engine.append", cat="session",
-                               sid=sid, n=len(data)):
-                s.backlog.append(data)
-                s.backlog_tuples += len(data)
-                s.stats.tuples_appended += len(data)
+            s.backlog.append(data)
+            s.backlog_tuples += len(data)
+            s.stats.tuples_appended += len(data)
             self._mx.appends.inc()
             self._mx.app_tuples.inc(len(data))
 
@@ -644,9 +671,13 @@ class SessionEngine:
         4. one vmapped ``run_chunks`` advances all lane states together
            -- per width segment, through the AOT bucket table when
            ``aot_buckets=`` is enabled.
+
+        The telemetry row's ``forced_sessions`` counts the ``force``
+        sessions: the queries this one flush answers.
         """
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
+        clock = _ScanClock()
         with self.obs.span("engine.flush", scope="engine") as sp:
             force = set(force)
             self._admit()
@@ -657,44 +688,34 @@ class SessionEngine:
             lane_masks: List[List[np.ndarray]] = [[] for _ in range(self.num_lanes)]
             lane_sid: List[Optional[int]] = [None] * self.num_lanes
             flushed_tuples = 0
-            for slot, sid in enumerate(self._slot_sid):
-                if sid is None:
-                    continue
-                s = self.sessions[sid]
-                lanes = self._lane_group(slot)
-                for ln in lanes:
-                    lane_sid[ln] = sid
-                gc, gm, n_real = self._take_striped(
-                    s, lanes, flush_tail=sid in force)
-                for g, ln in enumerate(lanes):
-                    lane_chunks[ln].extend(gc[g])
-                    lane_masks[ln].extend(gm[g])
-                flushed_tuples += n_real
+            with self.obs.span("flush.stripe", cat="sched"):
+                for slot, sid in enumerate(self._slot_sid):
+                    if sid is None:
+                        continue
+                    s = self.sessions[sid]
+                    lanes = self._lane_group(slot)
+                    for ln in lanes:
+                        lane_sid[ln] = sid
+                    gc, gm, n_real = self._take_striped(
+                        s, lanes, flush_tail=sid in force)
+                    for g, ln in enumerate(lanes):
+                        lane_chunks[ln].extend(gc[g])
+                        lane_masks[ln].extend(gm[g])
+                    flushed_tuples += n_real
 
             row_sessions = [None if sid is None else self.sessions[sid]
                             for sid in lane_sid]
-            width = 0
-            segs = list(self._segments(lane_chunks))
-            with self._segment_loop_span(segs, "engine") as seg_span:
-                for off, w in segs:
-                    with seg_span(off, w):
-                        chunks, mask = self._pack_chunks(
-                            lane_chunks, lane_masks, w, offset=off)
-                        if self._sharded is not None:  # split over the mesh
-                            chunks = jax.device_put(
-                                chunks, self._sharded.lane_sharding)
-                            mask = jax.device_put(
-                                mask, self._sharded.lane_sharding)
-                        run = self._aot.get(("eng", w), self._run_lanes)
-                        self._states, stats = run(self._states, chunks, mask)
-                        self._apply_exec_stats(
-                            stats, row_sessions,
-                            [min(max(len(c) - off, 0), w)
-                             for c in lane_chunks])
-                    width += w
+            # the engine-wide scan takes its batch split over the mesh
+            target = (None if self._sharded is None
+                      else self._sharded.lane_sharding)
+            self._states, width = self._scan_batch(
+                self._states, lane_chunks, lane_masks, row_sessions,
+                ("eng",), self._run_lanes, target, "engine", clock)
             sp.set(tuples=flushed_tuples, width=width)
         self._record_flush(flushed_tuples, lane_chunks, width, snap=snap,
-                           ms=(time.perf_counter() - t0) * 1e3)
+                           ms=(time.perf_counter() - t0) * 1e3,
+                           extra={**clock.columns(),
+                                  "forced_sessions": len(force)})
         self._flush_no += 1
 
     def flush_session(self, sid: int) -> None:
@@ -719,6 +740,7 @@ class SessionEngine:
         scan hits a pre-compiled bucket instead of retracing."""
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
+        clock = _ScanClock()
         s = self._session(sid)
         if s.slot is None:
             raise QueuedSessionError(
@@ -746,28 +768,18 @@ class SessionEngine:
                     [None] * (len(lanes) - n_real_lanes)
                 idx = np.asarray(lanes, np.int32)
                 sub = self._gather_group(self._states, idx)
-                segs = list(self._segments(group_chunks))
-                with self._segment_loop_span(segs, "session") as seg_span:
-                    for off, w in segs:
-                        with seg_span(off, w):
-                            arr, msk = self._pack_chunks(group_chunks,
-                                                         group_masks, w,
-                                                         offset=off)
-                            run = self._aot.get(("grp", len(lanes), w),
-                                                self._run_group)
-                            sub, stats = run(sub, arr, msk)
-                            self._apply_exec_stats(
-                                stats, row_sessions,
-                                [min(max(len(c) - off, 0), w)
-                                 for c in group_chunks])
-                        width += w
+                sub, width = self._scan_batch(
+                    sub, group_chunks, group_masks, row_sessions,
+                    ("grp", len(lanes)), self._run_group,
+                    self._group_target(), "session", clock)
                 self._states = self._scatter_group(self._states, idx, sub)
             sp.set(tuples=n_real, width=width)
         self._record_flush(n_real, group_chunks, width, scope="session",
-                           snap=snap, ms=(time.perf_counter() - t0) * 1e3)
+                           snap=snap, ms=(time.perf_counter() - t0) * 1e3,
+                           extra=clock.columns())
         self._flush_no += 1
 
-    def _flush_admission(self, sids: List[int]):
+    def _flush_admission(self, sids: List[int], clock: "_ScanClock"):
         """The storm flush behind ``open_batch``: run the newly admitted
         sessions' first backlog chunks as one batched lane-init plus one
         pow2-bucketed scan over their primary lanes.
@@ -785,12 +797,13 @@ class SessionEngine:
         fresh lane twice is a no-op, while resetting another session's
         lane would destroy it.
 
-        Returns ``(group_chunks, width, flushed_tuples,
-        n_scan_dispatches)`` for the caller's telemetry row."""
+        Returns ``(group_chunks, width, flushed_tuples)`` for the caller's
+        telemetry row; the scan's step times and segments add into
+        ``clock``."""
         live = [self.sessions[sid] for sid in sids
                 if self.sessions[sid].backlog_tuples >= self.chunk_size]
         if not live:
-            return [], 0, 0, 0
+            return [], 0, 0
         lanes = [s.slot for s in live]
         n_real_lanes = len(lanes)
         bucket = (self._admit_bucket(n_real_lanes) if self._aot_widths
@@ -821,21 +834,12 @@ class SessionEngine:
         row_sessions = live + [None] * (len(lanes) - n_real_lanes)
         idx = np.asarray(lanes, np.int32)
         sub = self._gather_group(self._states, idx)
-        width = n_disp = 0
-        for off, w in self._segments(group_chunks):
-            with self.obs.span("scan.segment", cat="scan", scope="admit",
-                               offset=off, width=w):
-                arr, msk = self._pack_chunks(group_chunks, group_masks, w,
-                                             offset=off)
-                run = self._aot.get(("grp", len(lanes), w), self._run_group)
-                sub, stats = run(sub, arr, msk)
-                self._apply_exec_stats(
-                    stats, row_sessions,
-                    [min(max(len(c) - off, 0), w) for c in group_chunks])
-            width += w
-            n_disp += 1
+        sub, width = self._scan_batch(
+            sub, group_chunks, group_masks, row_sessions,
+            ("grp", len(lanes)), self._run_group, self._group_target(),
+            "admit", clock)
         self._states = self._scatter_group(self._states, idx, sub)
-        return group_chunks, width, flushed, n_disp
+        return group_chunks, width, flushed
 
     # ------------------------------------------------------- AOT bucket table
 
@@ -854,29 +858,52 @@ class SessionEngine:
         gmax = min(1 + self.secondary_slots, self.num_lanes)
         return min(1 << (g - 1).bit_length(), gmax)
 
-    # per-flush ceiling on individual scan.segment spans: a 256-chunk
-    # flush through width-2 AOT buckets is 128 segments, and 128 span
-    # emits per flush is pure tracer churn on the hot path -- past the
-    # cap the whole loop gets ONE aggregate ``scan.segments`` span
-    # (args: n_segments, width) instead
-    _SEGMENT_SPAN_CAP = 16
+    def _scan_batch(self, state, lane_chunks, lane_masks, row_sessions,
+                    key: Tuple, run, target, scope: str,
+                    clock: "_ScanClock"):
+        """Advance ``state`` by every scan segment of one flush batch --
+        the segment loop of all three flush paths.  Each segment runs
+        three timed steps under its ``scan.segment`` span:
 
-    @contextlib.contextmanager
-    def _segment_loop_span(self, segs, scope: str):
-        """Context for a flush's segment loop, yielding the per-segment
-        span factory: detailed ``scan.segment`` spans up to
-        ``_SEGMENT_SPAN_CAP`` segments, ONE aggregate ``scan.segments``
-        span over the whole loop past it."""
-        if len(segs) <= self._SEGMENT_SPAN_CAP:
-            yield lambda off, w: self.obs.span(
-                "scan.segment", cat="scan", scope=scope,
-                offset=off, width=w)
-            return
-        null = contextlib.nullcontext()
-        with self.obs.span("scan.segments", cat="scan", scope=scope,
-                           n_segments=len(segs),
-                           width=sum(w for _, w in segs)):
-            yield lambda off, w: null
+        * ``scan.pack``: ``_pack_chunks``, the dense host batch;
+        * ``scan.h2d``: the batch copied to ``target`` (the device or
+          sharding the segment's executable takes; ``None`` is the
+          default device) and waited on;
+        * ``scan.run``: the compiled scan plus the wait for its stats
+          (``_apply_exec_stats``) -- device time as this thread sees it.
+
+        ``key`` + ``(width,)`` looks the executable up in the AOT table,
+        ``run`` is the jit fallback.  The step times add into ``clock``
+        whether tracing is on or off.  Returns ``(state, width)``."""
+        span = self.obs.span
+        width = 0
+        for off, w in self._segments(lane_chunks):
+            with span("scan.segment", cat="scan", scope=scope, offset=off,
+                      width=w):
+                t0 = time.perf_counter_ns()
+                with span("scan.pack", cat="scan"):
+                    chunks, mask = self._pack_chunks(
+                        lane_chunks, lane_masks, w, offset=off)
+                t1 = time.perf_counter_ns()
+                with span("scan.h2d", cat="scan"):
+                    chunks, mask = jax.block_until_ready(
+                        jax.device_put((chunks, mask), target))
+                t2 = time.perf_counter_ns()
+                with span("scan.run", cat="scan"):
+                    state, stats = self._aot.get(key + (w,), run)(
+                        state, chunks, mask)
+                    self._apply_exec_stats(
+                        stats, row_sessions,
+                        [min(max(len(c) - off, 0), w) for c in lane_chunks])
+                t3 = time.perf_counter_ns()
+            clock.add(t1 - t0, t2 - t1, t3 - t2)
+            width += w
+        return state, width
+
+    def _group_target(self):
+        """Where a lane group's scan batch goes: the one device the
+        per-session tier scans on (``_gather_group``)."""
+        return None if self._sharded is None else self._sharded.group_device
 
     def _segments(self, lane_chunks):
         """Yield the ``(offset, width)`` scan segments covering the
@@ -1055,9 +1082,8 @@ class SessionEngine:
         of each lane (the AOT segment loop); unfilled rows stay
         all-masked zero padding (exact no-ops).
 
-        Returns HOST (numpy) arrays on purpose: jit and AOT executables
-        take them directly, and the distributed flush path device_puts
-        host memory straight to each shard -- resharding an
+        Returns HOST (numpy) arrays on purpose: ``_scan_batch``
+        device_puts host memory straight to each shard -- resharding an
         already-device-resident array instead goes through jax's
         jit(_multi_slice), which compiles once per (shape, width) and
         would show up as steady-state retraces."""

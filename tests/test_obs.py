@@ -312,7 +312,11 @@ class TestEngineObservability:
             totals["tuples_flushed"]
         names = obs.tracer.span_names()
         assert {"engine.flush", "engine.flush_session", "scan.segment",
-                "merge.snapshot", "engine.append"} <= names
+                "scan.pack", "scan.h2d", "scan.run", "flush.stripe",
+                "merge.snapshot"} <= names
+        # appends are counted (appends_total), not timed
+        assert "engine.append" not in names
+        assert reg.get("appends_total").value() == 1.0
 
     def test_obs_off_is_bit_exact_and_silent(self, small_spec,
                                              zipf_dataset):
@@ -412,6 +416,140 @@ class TestEngineObservability:
                 "slot_reschedules", "backlog_tuples", "slot_occupancy",
                 "n_retraces", "compile_stall_ms", "flush_ms"} <= set(row)
         assert row["flush_ms"] is None or row["flush_ms"] >= 0.0
+
+
+# ------------------------------------------------- scan step timing
+_STEPS = ("scan.pack", "scan.h2d", "scan.run")
+
+
+def _profiled_flush(eng, sid, trace_dir):
+    """One forced engine-wide flush under the JAX profiler; returns the
+    host plane's events per thread line, as ``(name, start, end)``."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        eng.flush(force=(sid,))
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    return [[(e.name, e.start_ns, e.end_ns) for e in ln.events]
+            for pl in pd.planes if pl.name.startswith("/host:")
+            for ln in pl.lines]
+
+
+class TestScanStepTiming:
+    @pytest.mark.parametrize("on", [True, False])
+    def test_spans_nest_in_the_profiler(self, small_spec, zipf_dataset,
+                                        tmp_path, on):
+        """With the tracer on, ``engine.flush`` > ``scan.segment`` >
+        ``scan.pack``, ``scan.h2d``, ``scan.run`` land in the profiler's
+        host plane, nested and in order on one thread; with it off, no
+        span is annotated."""
+        eng = _engine(small_spec, aot_buckets=1, secondary_slots=0,
+                      obs=Observability(enabled=on))
+        sid = eng.open(tenant="a")
+        eng.append(sid, zipf_dataset(2 * SMALL_CHUNK + 5, DOMAIN, 1.5))
+        lines = _profiled_flush(eng, sid, tmp_path)
+        ours = {"engine.flush", "scan.segment", "flush.stripe", *_STEPS}
+        found = [[e for e in ln if e[0] in ours] for ln in lines]
+        found = [ln for ln in found if ln]
+        if not on:
+            assert found == []
+            return
+        assert len(found) == 1                      # one thread
+        evs = sorted(found[0], key=lambda e: (e[1], -e[2]))
+        flush = [e for e in evs if e[0] == "engine.flush"]
+        segs = [e for e in evs if e[0] == "scan.segment"]
+        assert len(flush) == 1 and len(segs) == 3   # 3 chunks, width 1
+        f0, f1 = flush[0][1:]
+        for s0, s1 in (e[1:] for e in segs):
+            assert f0 <= s0 and s1 <= f1
+            steps = [e for e in evs
+                     if e[0] in _STEPS and s0 <= e[1] and e[2] <= s1]
+            assert [e[0] for e in steps] == list(_STEPS)
+            assert all(a[2] <= b[1] for a, b in zip(steps, steps[1:]))
+
+    @pytest.mark.parametrize("on", [True, False])
+    def test_step_columns_in_both_scopes(self, small_spec, zipf_dataset,
+                                         on):
+        """Every flush row carries its scan steps' times and segment
+        count, tracing on or off; engine rows count the forced
+        sessions."""
+        eng = _engine(small_spec, aot_buckets=1, primary_slots=3,
+                      obs=Observability(enabled=on))
+        sids = [eng.open(tenant=t) for t in "abc"]
+        for i, sid in enumerate(sids):
+            eng.append(sid, zipf_dataset((2 + i) * SMALL_CHUNK + 7, DOMAIN,
+                                         1.5, seed=i))
+        eng.flush(force=sids[:2])
+        eng.append(sids[2], zipf_dataset(3 * SMALL_CHUNK, DOMAIN, 0.8))
+        eng.query(sids[2], scope="session")
+        rows = list(eng._telemetry)
+        assert [r["scope"] for r in rows] == ["engine", "session"]
+        assert rows[0]["forced_sessions"] == 2
+        assert "forced_sessions" not in rows[1]
+        for r in rows:
+            steps = [r["pack_ms"], r["h2d_ms"], r["run_ms"]]
+            assert min(steps) >= 0.0
+            # aot_buckets=1: one segment per chunk of the widest lane
+            assert r["segments"] == r["lane_width"] >= 2
+            # each column is rounded to 1 us
+            assert sum(steps) <= r["flush_ms"] + 0.002
+
+    def test_storm_row_counts_its_segments(self, small_spec, zipf_dataset):
+        eng = _engine(small_spec, aot_buckets=1, primary_slots=4,
+                      secondary_slots=0)
+        eng.open_batch(["a", "b"], first=[
+            zipf_dataset(2 * SMALL_CHUNK, DOMAIN, 1.5, seed=1),
+            zipf_dataset(SMALL_CHUNK + 3, DOMAIN, 1.5, seed=2)])
+        row = list(eng._telemetry)[-1]
+        assert row["scope"] == "admit"
+        assert row["segments"] == row["n_scan_dispatches"] == 2
+        assert min(row["pack_ms"], row["h2d_ms"], row["run_ms"]) >= 0.0
+
+    def test_every_segment_gets_its_span(self, small_spec, zipf_dataset):
+        """A flush of 20 one-chunk segments emits 20 ``scan.segment``
+        spans, each with its three steps; no aggregate span."""
+        obs = Observability()
+        eng = _engine(small_spec, aot_buckets=1, secondary_slots=0,
+                      obs=obs)
+        sid = eng.open(tenant="a")
+        eng.append(sid, zipf_dataset(20 * SMALL_CHUNK, DOMAIN, 1.5))
+        obs.tracer.clear()
+        eng.flush()
+        names = [e["name"] for e in obs.tracer.events()]
+        assert names.count("scan.segment") == 20
+        for step in _STEPS:
+            assert names.count(step) == 20
+        assert "scan.segments" not in names
+        assert list(eng._telemetry)[-1]["segments"] == 20
+
+    @pytest.mark.parametrize("mesh", [False, True], ids=["local", "mesh1"])
+    @pytest.mark.parametrize("aot", [None, 1])
+    def test_explicit_copy_is_bit_exact(self, small_spec, zipf_dataset,
+                                        mesh, aot):
+        """Answers through every flush path (storm, engine-wide,
+        per-session) equal the oracle with the batch copied to the
+        device before the scan, local and on a mesh of one."""
+        import jax
+        m = jax.make_mesh((1,), ("lanes",)) if mesh else None
+        eng = _engine(small_spec, aot_buckets=aot, primary_slots=3,
+                      mesh=m)
+        data = [zipf_dataset(n, DOMAIN, a, seed=k) for k, (n, a) in
+                enumerate([(3 * SMALL_CHUNK + 11, 2.0),
+                           (SMALL_CHUNK + 1, 0.8), (2 * SMALL_CHUNK, 1.5)])]
+        sids = eng.open_batch(["a", "b", "c"], first=data)
+        more = zipf_dataset(2 * SMALL_CHUNK + 9, DOMAIN, 2.0, seed=7)
+        eng.append(sids[0], more)
+        eng.flush(force=sids[:2])
+        np.testing.assert_array_equal(
+            np.asarray(eng.query(sids[0], scope="session")),
+            _oracle(np.concatenate([data[0][:, 0], more[:, 0]])))
+        for sid, d in zip(sids[1:], data[1:]):
+            merged, _ = eng.close(sid)
+            np.testing.assert_array_equal(merged, _oracle(d[:, 0]))
 
 
 # ------------------------------------------------- recovery observability
