@@ -212,6 +212,13 @@ class ShardedLaneExecutor:
       run_lanes(states, chunks, mask)  one shard_map'd step: every shard
                                        vmaps the chunk scan over its
                                        local lanes (zero communication)
+      take_local(states, idx)          the lane bucket of a flush: every
+                                       shard gathers its own local lanes
+                                       ``idx`` (an equal count per
+                                       shard; no lane crosses devices)
+      put_local(states, idx, sub)      its inverse: every shard writes
+                                       its slice of ``sub`` back into
+                                       its local lanes ``idx``
       fold_lane(states, src, dst)      merge-before-reassign as a
                                        collective: src's merged buffers
                                        are masked out locally, psum'd
@@ -238,6 +245,8 @@ class ShardedLaneExecutor:
     lanes_per_device: int
     lane_sharding: NamedSharding
     run_lanes: Callable = dataclasses.field(repr=False)
+    take_local: Callable = dataclasses.field(repr=False)
+    put_local: Callable = dataclasses.field(repr=False)
     fold_lane: Optional[Callable] = dataclasses.field(repr=False)
     merge_lane: Callable = dataclasses.field(repr=False)
     reset_lane: Callable = dataclasses.field(repr=False)
@@ -253,24 +262,6 @@ class ShardedLaneExecutor:
         """Re-pin a lanes-stacked state to the lane sharding (after a
         host-side or cross-shard edit, e.g. ``executor.put_lanes``)."""
         return jax.device_put(states, self.lane_sharding)
-
-    @property
-    def group_device(self):
-        """The mesh's first device, where the per-session tier scans a
-        gathered lane group: a Pallas kernel cannot be partitioned
-        automatically, so a program that holds one runs on one device or
-        under ``shard_map``."""
-        return self.mesh.devices.flat[0]
-
-    def to_group_device(self, tree):
-        """Move a gathered lane group (``executor.take_lanes``) onto
-        ``group_device``."""
-        return jax.device_put(tree, self.group_device)
-
-    def replicate(self, tree):
-        """Replicate a lane group over the mesh, ready to be scattered
-        back into the lane-sharded state (``executor.put_lanes``)."""
-        return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
 
 def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
@@ -330,6 +321,12 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
     run_lanes = jax.jit(jax.shard_map(
         _run, mesh=mesh, in_specs=(pspec, pspec, pspec),
         out_specs=(pspec, pspec)))
+    take_local = jax.jit(jax.shard_map(
+        core_executor.take_lanes, mesh=mesh, in_specs=(pspec, pspec),
+        out_specs=pspec))
+    put_local = jax.jit(jax.shard_map(
+        core_executor.put_lanes, mesh=mesh, in_specs=(pspec, pspec, pspec),
+        out_specs=pspec))
 
     def _merge(states, i):
         picked = merge_selected(states, local_ids() == i)
@@ -370,5 +367,6 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
     return ShardedLaneExecutor(
         res=res, mesh=mesh, num_lanes=num_lanes, axis=axis,
         lanes_per_device=lanes_per_device, lane_sharding=sharding,
-        run_lanes=run_lanes, fold_lane=fold_lane, merge_lane=merge_lane,
+        run_lanes=run_lanes, take_local=take_local, put_local=put_local,
+        fold_lane=fold_lane, merge_lane=merge_lane,
         reset_lane=reset_lane)

@@ -569,6 +569,7 @@ class DurableSessionEngine(SessionEngine):
                 int(sid_s), ent["tenant"], slot=ent["slot"],
                 backlog=backlog, backlog_tuples=n,
                 stats=SessionStats(**ent["stats"]), closed=ent["closed"])
+        self._index_backlog()
 
     # -------------------------------------------------------------- recovery
     def _recover(self) -> None:

@@ -43,27 +43,36 @@ Latency tiering (per-session flush)
   remains the engine-wide path (and the only place slot re-scheduling
   happens); both produce identical results for any interleaving.
 
+Lane buckets (active-lane flushes)
+  Every flush -- engine-wide, per-session, admission -- scans only the
+  lanes that carry a chunk: they are gathered out of the lane table
+  into a **lane bucket**, the power-of-two ceiling of their count
+  (capped at the lanes a device holds), padded with other lanes that
+  carry all-masked chunks (exact no-ops -- a padded lane's state rides
+  through the scan bit-identically), scanned, and scattered back.  A
+  flush costs what its active lanes cost, not what the lane table
+  holds, and its host bookkeeping walks only the sessions with work.
+
 Distributed mode (DESIGN.md §9, docs/distributed.md)
   ``SessionEngine(mesh=...)`` shards the lane axis over the mesh's
   ``lanes`` axis via ``core.distributed.make_lane_sharded_executor``:
   P devices x lanes_per_device lanes, one engine serving more tenants
-  than one device's lane budget.  Flushes stay collective-free (lanes
-  are independent streams, shard_map + local vmap); a cross-device slot
-  re-grant runs the §IV-B shadow-buffer merge as a psum over the lanes
-  axis.  A mesh of size 1 is bit-exact vs the unsharded engine.
+  than one device's lane budget.  Flushes stay collective-free: each
+  device gathers its own active lanes into the bucket of the busiest
+  device's count and the shard_map'd scan vmaps over them; a
+  cross-device slot re-grant runs the §IV-B shadow-buffer merge as a
+  psum over the lanes axis.  A mesh of size 1 is bit-exact vs the
+  unsharded engine.
 
 AOT shape buckets (compile-stall elimination)
   Ragged appends produce ragged flush batches, and every new
-  (lane count, scan width) shape is a fresh jit trace -- a silent
+  (lane bucket, scan width) shape is a fresh jit trace -- a silent
   multi-hundred-ms stall on the flush path.  With
-  ``SessionEngine(aot_buckets=W)`` both flush tiers route through a
-  **bucket table**: scan widths round up to powers of two (as before)
-  and are chopped into segments of at most ``W``; per-session lane
-  groups round up to power-of-two buckets padded with all-masked zero
-  lanes (exact no-ops -- a padded lane's state rides through the scan
-  bit-identically).  ``warmup()`` AOT-lowers and compiles ONE
-  executable per bucket up front (``jit(scan_lanes).lower().compile()``
-  on ``core.executor.ResumableExecutor.scan_lanes``, local and mesh
+  ``SessionEngine(aot_buckets=W)`` scan widths round up to powers of
+  two and are chopped into segments of at most ``W``, and ``warmup()``
+  AOT-lowers and compiles ONE executable per (lane bucket, width) up
+  front (``jit(scan_lanes).lower().compile()`` on
+  ``core.executor.ResumableExecutor.scan_lanes``, local and mesh
   variants alike) and primes every fixed-shape helper, so steady-state
   traffic -- however ragged -- never compiles again.  Warmup runs
   explicitly or at the first ``append`` (when the tuple dtype/shape
@@ -77,12 +86,11 @@ Batched admission (session storms)
   ``open_batch(tenants, first=...)`` packs the whole storm -- every
   open plus its first append -- into ONE batched lane-init (a single
   gather-free ``x.at[idx].set`` over all admitted lanes) and one
-  pow2-bucketed scan over the admitted primary lanes, chopped into the
+  lane-bucketed scan over the admitted primary lanes, chopped into the
   same AOT width segments as a flush: O(buckets) dispatches for a
-  thousand-session storm.  Admission lane-group shapes (the pow2
-  ceiling of the admitted count, capped at ``primary_slots``) are part
-  of the ``warmup()`` table, so the zero-steady-retrace invariant
-  holds THROUGH storms, local and mesh alike.  Ragged first-append
+  thousand-session storm.  The storm's scan uses the lane buckets of
+  every flush, so the zero-steady-retrace invariant holds THROUGH
+  storms, local and mesh alike.  Ragged first-append
   tails stay buffered (answers are chunking-invariant), keeping the
   storm path bit-exact vs serial admission.  Overflow is strictly
   FIFO: tenants past ``primary_slots`` queue in ``open_batch`` call
@@ -105,12 +113,14 @@ Telemetry + observability (DESIGN.md §11, docs/observability.md)
   metrics registry (``flush_latency_ms{scope}``, ``lane_occupancy
   {lane}``, ``secondary_grants_total{tenant}``, ``backlog_depth
   {tenant}``, retrace counters -- Prometheus-exportable) and a span
-  tracer (``engine.flush`` / ``scan.segment`` > ``scan.pack`` /
-  ``scan.h2d`` / ``scan.run`` / ``engine.admit_storm`` /
-  ``merge.snapshot`` ... as Perfetto ``trace_event`` JSON, each span
-  also a JAX profiler annotation).  Every flush row carries its scan
-  steps' host times (``pack_ms``, ``h2d_ms``, ``run_ms``, ``segments``)
-  whether tracing is on or off.  Pass one
+  tracer (``engine.flush`` / ``scan.gather`` / ``scan.segment`` >
+  ``scan.pack`` / ``scan.h2d`` / ``scan.run`` / ``scan.scatter`` /
+  ``engine.admit_storm`` / ``merge.snapshot`` ... as Perfetto
+  ``trace_event`` JSON, each span also a JAX profiler annotation).
+  Every flush row carries its scan steps' host times (``gather_ms``,
+  ``pack_ms``, ``h2d_ms``, ``run_ms``, ``segments``) and its lane
+  counts (``scan_lanes``, ``active_lanes``) whether tracing is on or
+  off.  Pass one
   ``Observability`` to share a registry across engines, ``obs=False``
   to disable (every op an early return -- the serving bench asserts
   the enabled overhead stays under its bound).
@@ -185,15 +195,19 @@ class _Session:
 
 
 class _ScanClock:
-    """Host time one flush spent in each step of its scan segments
-    (``SessionEngine._scan_batch``), summed over the segments; becomes
+    """Host time one flush spent in each step of its scan
+    (``SessionEngine._scan_lanes`` and its segments), summed; becomes
     the telemetry row's ``pack_ms`` / ``h2d_ms`` / ``run_ms`` /
-    ``segments`` columns."""
+    ``segments`` columns, ``gather_ms`` (the lane bucket's gather plus
+    scatter), ``scan_lanes`` (the lanes the scan presented, pads
+    included) and ``active_lanes`` (the lanes that carried a chunk)."""
 
-    __slots__ = ("pack_ns", "h2d_ns", "run_ns", "segments")
+    __slots__ = ("pack_ns", "h2d_ns", "run_ns", "segments", "gather_ns",
+                 "scan_lanes", "active_lanes")
 
     def __init__(self):
         self.pack_ns = self.h2d_ns = self.run_ns = self.segments = 0
+        self.gather_ns = self.scan_lanes = self.active_lanes = 0
 
     def add(self, pack_ns: int, h2d_ns: int, run_ns: int) -> None:
         self.pack_ns += pack_ns
@@ -201,11 +215,20 @@ class _ScanClock:
         self.run_ns += run_ns
         self.segments += 1
 
+    def lanes(self, gather_ns: int, scan_lanes: int,
+              active_lanes: int) -> None:
+        self.gather_ns += gather_ns
+        self.scan_lanes += scan_lanes
+        self.active_lanes += active_lanes
+
     def columns(self) -> Dict[str, Any]:
         return {"pack_ms": round(self.pack_ns / 1e6, 3),
                 "h2d_ms": round(self.h2d_ns / 1e6, 3),
                 "run_ms": round(self.run_ns / 1e6, 3),
-                "segments": self.segments}
+                "segments": self.segments,
+                "gather_ms": round(self.gather_ns / 1e6, 3),
+                "scan_lanes": self.scan_lanes,
+                "active_lanes": self.active_lanes}
 
 
 class _EngineMetrics:
@@ -328,8 +351,8 @@ class SessionEngine:
         pre-compiles one executable per (lane bucket, width in
         1,2,...,W) and wider flushes chop into W-wide segments, so a
         warmed engine NEVER retraces on the flush path.  ``None``
-        (default) keeps the plain jit path (one retrace per fresh
-        shape, ``_batch_width`` keeping them logarithmic).
+        (default) compiles on first use (one retrace per fresh shape,
+        lane buckets and ``_batch_width`` keeping them logarithmic).
       **executor_kw: forwarded to ``core.make_resumable_executor``
         (profile_chunks, threshold, mem_width_tuples, kernel_backend).
     """
@@ -375,53 +398,52 @@ class SessionEngine:
         fresh = self._res.init_state()
         self._fresh = fresh
         self._sharded = None
+        # every flush path scans a BUCKET of lanes: the lanes that carry
+        # a chunk, gathered (per device under ``mesh=``) into a
+        # power-of-two lane count, scanned, scattered back
+        self._take_lanes = jax.jit(core_executor.take_lanes)
+        self._put_lanes = jax.jit(core_executor.put_lanes)
         if mesh is not None:
             from repro.core import distributed as core_distributed
             self._sharded = core_distributed.make_lane_sharded_executor(
                 self._res, mesh, self.num_lanes, axis=lanes_axis)
             self.lanes_per_device = self._sharded.lanes_per_device
             self._states = self._sharded.init_states()
-            self._run_lanes = self._sharded.run_lanes
+            self._gather = self._sharded.take_local
+            self._scatter = self._sharded.put_local
+            self._run = self._sharded.run_lanes
             self._merge_lane = self._sharded.merge_lane
-            self._reset_lane = self._sharded.reset_lane
             if spec.merge is None:
                 self._fold_lane = self._sharded.fold_lane
         else:
             self.lanes_per_device = self.num_lanes
             self._states = core_executor.stack_states(fresh, self.num_lanes)
-            self._run_lanes = jax.jit(self._res.scan_lanes)
+            self._gather = self._take_lanes
+            self._scatter = self._put_lanes
+            self._run = jax.jit(self._res.scan_lanes)
             self._merge_lane = jax.jit(
                 lambda states, i: self._res.merge_state(
                     jax.tree.map(lambda x: x[i], states)))
-            self._reset_lane = jax.jit(
-                lambda states, i: jax.tree.map(
-                    lambda x, f: x.at[i].set(f), states, self._fresh))
             if spec.merge is None:
                 self._fold_lane = jax.jit(self._fold_lane_impl)
-        # per-session flush runs the lane GROUP locally in both modes:
-        # take_lanes gathers the group's ExecStates across device
-        # boundaries, the vmapped scan resumes them here, put_lanes
-        # scatters them back (cross-device suspend/resume, DESIGN.md §9)
-        self._run_group = jax.jit(self._res.scan_lanes)
-        self._take_lanes = jax.jit(core_executor.take_lanes)
-        self._put_lanes = jax.jit(core_executor.put_lanes)
         # batched lane-init: reset a GROUP of lanes to fresh state in one
         # dispatch (close's group reset, the storm-admission lane-init).
         # Duplicate indices are legal -- the same fresh value lands twice
-        # -- so fixed-shape callers may pad idx by repeating a lane.
+        # -- so callers pad idx up to a lane bucket by repeating a lane.
         self._reset_lanes = jax.jit(
             lambda states, idx: jax.tree.map(
                 lambda x, f: x.at[idx].set(f), states, self._fresh))
 
-        # --- AOT shape buckets: widths 1,2,...,W plus the power-of-two
-        # lane-group sizes a per-session flush can present (capped at
-        # num_lanes -- padding never outgrows the lane table)
-        self._aot: Dict[Tuple, Any] = {}      # bucket key -> compiled exec
+        # --- the lane buckets: 1, 2, 4, ... lanes per device, capped at
+        # lanes_per_device (padding never outgrows the lane table); with
+        # aot_buckets=W also the scan widths 1, 2, ..., W
+        self._lane_buckets = tuple(sorted(
+            {self._lane_bucket(1 << k)
+             for k in range(self.lanes_per_device.bit_length() + 1)}))
+        self._aot: Dict[Tuple, Any] = {}      # (bucket, width) -> exec
         self._aot_info: Optional[Dict[str, Any]] = None
         if aot_buckets is None:
             self._aot_widths = None
-            self._group_buckets: Tuple[int, ...] = ()
-            self._admit_buckets: Tuple[int, ...] = ()
         else:
             if isinstance(aot_buckets, (int, np.integer)):
                 max_w = int(aot_buckets)
@@ -434,12 +456,6 @@ class SessionEngine:
             max_w = 1 << (max_w - 1).bit_length()        # pow2 ceiling
             self._aot_widths = tuple(1 << k
                                      for k in range(max_w.bit_length()))
-            self._group_buckets = tuple(sorted(
-                {self._group_bucket(g)
-                 for g in range(1, 2 + self.secondary_slots)}))
-            self._admit_buckets = tuple(sorted(
-                {self._admit_bucket(k)
-                 for k in range(1, 1 + self.primary_slots)}))
 
         # jit the slot scheduler ONCE: schedule_secpes builds its scan
         # eagerly, which re-traces (and re-compiles) on every call --
@@ -460,6 +476,11 @@ class SessionEngine:
         self._n_retraces_admit = 0         # compiles observed during storms
 
         self.sessions: Dict[int, _Session] = {}
+        # host index of the work: per-flush bookkeeping walks these
+        # sids, never the whole session table
+        self._pending: set = set()          # sids holding buffered tuples
+        self._backlog_total = 0             # their buffered tuples
+        self._depth_shown: set = set()      # tenants with a depth gauge
         self._queue: Deque[int] = deque()                # sids awaiting a slot
         self._slot_sid: List[Optional[int]] = [None] * primary_slots
         self._free_slots: List[int] = list(range(primary_slots))  # min-heap
@@ -584,6 +605,8 @@ class SessionEngine:
         if len(data):
             s.backlog.append(data)
             s.backlog_tuples += len(data)
+            self._pending.add(sid)
+            self._backlog_total += len(data)
             s.stats.tuples_appended += len(data)
             self._mx.appends.inc()
             self._mx.app_tuples.inc(len(data))
@@ -641,10 +664,7 @@ class SessionEngine:
                     self._sec_assign[j] = -1
             # one batched reset of the whole lane group (primary +
             # granted secondaries) instead of one dispatch per lane
-            states = self._reset_lanes(self._states,
-                                       np.asarray(lanes, np.int32))
-            self._states = (states if self._sharded is None
-                            else self._sharded.shard_states(states))
+            self._reset_group(lanes)
             self._slot_sid[s.slot] = None
             heapq.heappush(self._free_slots, s.slot)
             s.slot = None
@@ -667,10 +687,11 @@ class SessionEngine:
            merge into its old session first (shadow-buffer semantics);
         3. stripe each session's full chunks across its lane group (the
            ``force`` sessions also flush their ragged tail as a masked
-           chunk); idle lanes carry all-masked padding;
-        4. one vmapped ``run_chunks`` advances all lane states together
-           -- per width segment, through the AOT bucket table when
-           ``aot_buckets=`` is enabled.
+           chunk) -- only sessions with work are walked;
+        4. the lanes that carry a chunk are gathered into a lane bucket
+           (``_scan_lanes``), one vmapped scan advances them together --
+           per width segment, through the AOT bucket table when
+           ``aot_buckets=`` is enabled -- and they are scattered back.
 
         The telemetry row's ``forced_sessions`` counts the ``force``
         sessions: the queries this one flush answers.
@@ -684,33 +705,30 @@ class SessionEngine:
             with self.obs.span("sched.regrant", cat="sched"):
                 self._reschedule_secondary()
 
-            lane_chunks: List[List[np.ndarray]] = [[] for _ in range(self.num_lanes)]
-            lane_masks: List[List[np.ndarray]] = [[] for _ in range(self.num_lanes)]
-            lane_sid: List[Optional[int]] = [None] * self.num_lanes
+            lanes: List[int] = []
+            lane_chunks: List[List[np.ndarray]] = []
+            lane_masks: List[List[np.ndarray]] = []
+            row_sessions: List[_Session] = []
             flushed_tuples = 0
             with self.obs.span("flush.stripe", cat="sched"):
-                for slot, sid in enumerate(self._slot_sid):
-                    if sid is None:
-                        continue
-                    s = self.sessions[sid]
-                    lanes = self._lane_group(slot)
-                    for ln in lanes:
-                        lane_sid[ln] = sid
+                groups = self._secondary_groups()
+                work = [self.sessions[sid] for sid in self._pending]
+                for s in sorted((s for s in work if s.slot is not None
+                                 and (s.sid in force or s.backlog_tuples
+                                      >= self.chunk_size)),
+                                key=lambda s: s.slot):
+                    group = [s.slot, *groups.get(s.slot, ())]
                     gc, gm, n_real = self._take_striped(
-                        s, lanes, flush_tail=sid in force)
-                    for g, ln in enumerate(lanes):
-                        lane_chunks[ln].extend(gc[g])
-                        lane_masks[ln].extend(gm[g])
+                        s, group, flush_tail=s.sid in force)
+                    for ln, c, m in zip(group, gc, gm):
+                        if c:
+                            lanes.append(ln)
+                            lane_chunks.append(c)
+                            lane_masks.append(m)
+                            row_sessions.append(s)
                     flushed_tuples += n_real
-
-            row_sessions = [None if sid is None else self.sessions[sid]
-                            for sid in lane_sid]
-            # the engine-wide scan takes its batch split over the mesh
-            target = (None if self._sharded is None
-                      else self._sharded.lane_sharding)
-            self._states, width = self._scan_batch(
-                self._states, lane_chunks, lane_masks, row_sessions,
-                ("eng",), self._run_lanes, target, "engine", clock)
+            width = self._scan_lanes(lanes, lane_chunks, lane_masks,
+                                     row_sessions, "engine", clock)
             sp.set(tuples=flushed_tuples, width=width)
         self._record_flush(flushed_tuples, lane_chunks, width, snap=snap,
                            ms=(time.perf_counter() - t0) * 1e3,
@@ -721,23 +739,18 @@ class SessionEngine:
     def flush_session(self, sid: int) -> None:
         """Advance ONLY this session's stream: its backlog (ragged tail
         included, as a masked chunk) stripes across its current lane
-        group and a single vmapped scan over <= 1 + granted lanes runs
-        it -- the latency-tiering fast path behind ``query``.
+        group and a single vmapped scan over the group's lanes that
+        carry a chunk runs it -- the latency-tiering fast path behind
+        ``query``.
 
         No admission and no secondary re-scheduling happen here (both
         stay on the engine-wide ``flush``), so the cost is bounded by
-        this session's own backlog.  In distributed mode the lane group
-        is gathered across device boundaries (``executor.take_lanes``),
-        resumed locally, and scattered back -- when all of the session's
-        lanes live on one device, the gather touches a single shard (the
-        local-shard fast path).
-
-        With ``aot_buckets=`` enabled the lane group rounds up to a
-        power-of-two bucket, padded with lanes OUTSIDE the group
-        carrying all-masked zero chunks: a fully masked scan leaves an
-        ``ExecState`` bit-identical (the executor's validity-mask
-        no-op), so the padded lanes are written back unchanged and the
-        scan hits a pre-compiled bucket instead of retracing."""
+        this session's own backlog.  The lanes go through the same lane
+        bucket as every flush (``_scan_lanes``): padded with lanes
+        OUTSIDE the group carrying all-masked chunks -- a fully masked
+        scan leaves an ``ExecState`` bit-identical (the executor's
+        validity-mask no-op), so the padded lanes are written back
+        unchanged and the scan hits a pre-compiled bucket."""
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
         clock = _ScanClock()
@@ -749,30 +762,13 @@ class SessionEngine:
                 "to admit it first")
         with self.obs.span("engine.flush_session", scope="session",
                            sid=sid, tenant=s.tenant) as sp:
-            lanes = self._lane_group(s.slot)
-            group_chunks, group_masks, n_real = self._take_striped(
-                s, lanes, flush_tail=True)
-            width = 0
-            if any(group_chunks):
-                n_real_lanes = len(lanes)
-                if self._aot_widths:
-                    bucket = self._group_bucket(n_real_lanes)
-                    if bucket > n_real_lanes:
-                        in_group = set(lanes)
-                        pads = [ln for ln in range(self.num_lanes)
-                                if ln not in in_group][:bucket - n_real_lanes]
-                        lanes = lanes + pads
-                        group_chunks = group_chunks + [[] for _ in pads]
-                        group_masks = group_masks + [[] for _ in pads]
-                row_sessions = [s] * n_real_lanes + \
-                    [None] * (len(lanes) - n_real_lanes)
-                idx = np.asarray(lanes, np.int32)
-                sub = self._gather_group(self._states, idx)
-                sub, width = self._scan_batch(
-                    sub, group_chunks, group_masks, row_sessions,
-                    ("grp", len(lanes)), self._run_group,
-                    self._group_target(), "session", clock)
-                self._states = self._scatter_group(self._states, idx, sub)
+            group = self._lane_group(s.slot)
+            gc, gm, n_real = self._take_striped(s, group, flush_tail=True)
+            live = [k for k, c in enumerate(gc) if c]
+            group_chunks = [gc[k] for k in live]
+            width = self._scan_lanes([group[k] for k in live], group_chunks,
+                                     [gm[k] for k in live], [s] * len(live),
+                                     "session", clock)
             sp.set(tuples=n_real, width=width)
         self._record_flush(n_real, group_chunks, width, scope="session",
                            snap=snap, ms=(time.perf_counter() - t0) * 1e3,
@@ -782,20 +778,15 @@ class SessionEngine:
     def _flush_admission(self, sids: List[int], clock: "_ScanClock"):
         """The storm flush behind ``open_batch``: run the newly admitted
         sessions' first backlog chunks as one batched lane-init plus one
-        pow2-bucketed scan over their primary lanes.
+        bucketed scan over their primary lanes.
 
         Only FULL chunks run (``flush_tail=False``): answers are
         chunking-invariant, so deferring ragged tails to the next
         query/close keeps the path bit-exact vs serial admission, and a
         session whose first append is sub-chunk costs zero dispatches.
         A newly admitted session holds no secondary grants, so its lane
-        group is exactly its primary lane -- the storm group is the
-        admitted lanes, padded up to the admission bucket with OTHER
-        real lanes carrying all-masked chunks (written back
-        bit-identically, the ``flush_session`` pad rule).  The lane-init
-        idx pads with DUPLICATE admitted lanes instead: resetting a
-        fresh lane twice is a no-op, while resetting another session's
-        lane would destroy it.
+        group is exactly its primary lane; the scan goes through the
+        lane bucket of every flush (``_scan_lanes``).
 
         Returns ``(group_chunks, width, flushed_tuples)`` for the caller's
         telemetry row; the scan's step times and segments add into
@@ -805,16 +796,9 @@ class SessionEngine:
         if not live:
             return [], 0, 0
         lanes = [s.slot for s in live]
-        n_real_lanes = len(lanes)
-        bucket = (self._admit_bucket(n_real_lanes) if self._aot_widths
-                  else n_real_lanes)
-        init_idx = lanes + [lanes[0]] * (bucket - n_real_lanes)
         with self.obs.span("admit.lane_init", cat="admit",
-                           n_lanes=n_real_lanes, bucket=bucket):
-            states = self._reset_lanes(self._states,
-                                       np.asarray(init_idx, np.int32))
-            self._states = (states if self._sharded is None
-                            else self._sharded.shard_states(states))
+                           n_lanes=len(lanes)):
+            self._reset_group(lanes)
         group_chunks: List[List[np.ndarray]] = []
         group_masks: List[List[np.ndarray]] = []
         flushed = 0
@@ -824,58 +808,112 @@ class SessionEngine:
             group_chunks.append(gc[0])
             group_masks.append(gm[0])
             flushed += n_real
-        if bucket > n_real_lanes:
-            in_group = set(lanes)
-            pads = [ln for ln in range(self.num_lanes)
-                    if ln not in in_group][:bucket - n_real_lanes]
-            lanes = lanes + pads
-            group_chunks += [[] for _ in pads]
-            group_masks += [[] for _ in pads]
-        row_sessions = live + [None] * (len(lanes) - n_real_lanes)
-        idx = np.asarray(lanes, np.int32)
-        sub = self._gather_group(self._states, idx)
-        sub, width = self._scan_batch(
-            sub, group_chunks, group_masks, row_sessions,
-            ("grp", len(lanes)), self._run_group, self._group_target(),
-            "admit", clock)
-        self._states = self._scatter_group(self._states, idx, sub)
+        width = self._scan_lanes(lanes, group_chunks, group_masks, live,
+                                 "admit", clock)
         return group_chunks, width, flushed
 
-    # ------------------------------------------------------- AOT bucket table
+    # ------------------------------------------------------ lane buckets
 
-    def _admit_bucket(self, k: int) -> int:
-        """Admission-storm lane bucket: the power-of-two ceiling of the
-        ``k`` admitted sessions, capped at ``primary_slots`` -- a storm
-        can never admit more than every primary lane, so the full-house
-        storm pays no padding and the pad lanes always exist."""
-        return min(1 << (k - 1).bit_length(), self.primary_slots)
-
-    def _group_bucket(self, g: int) -> int:
-        """Lane-group bucket: the power-of-two ceiling of ``g``, capped
-        at the LARGEST group a session can own (its primary lane + every
-        secondary lane) -- the maximal group never pays padding, and the
+    def _lane_bucket(self, n: int) -> int:
+        """Lanes per device a scan of ``n`` lanes (on its busiest
+        device) presents: the power-of-two ceiling of ``n``, capped at
+        ``lanes_per_device`` -- the full table pays no padding and the
         padding lanes always exist."""
-        gmax = min(1 + self.secondary_slots, self.num_lanes)
-        return min(1 << (g - 1).bit_length(), gmax)
+        return min(1 << (n - 1).bit_length(), self.lanes_per_device)
+
+    def _bucket_rows(self, lanes: List[int]):
+        """Lay the distinct lane ids ``lanes`` out as a lane bucket.
+
+        Each device takes its own lanes (none cross devices) and pads
+        them up to ``b``, the bucket of the busiest device's count,
+        with other lanes of the same device, which will carry all-masked
+        chunks.  Returns ``(rows, idx, b)``: ``rows[k]`` is the position
+        in ``lanes`` of the bucket's row ``k`` (``-1`` for a pad) and
+        ``idx[k]`` its lane id on its device; device ``d`` holds rows
+        ``d*b .. (d+1)*b - 1``.  O(bucket + lanes)."""
+        lpd = self.lanes_per_device
+        per: List[List[int]] = [[] for _ in range(self.num_lanes // lpd)]
+        for pos, ln in enumerate(lanes):
+            per[ln // lpd].append(pos)
+        b = self._lane_bucket(max(len(p) for p in per))
+        rows: List[int] = []
+        idx: List[int] = []
+        for d, ps in enumerate(per):
+            local = [lanes[p] - d * lpd for p in ps]
+            taken = set(local)
+            pads = (i for i in range(lpd) if i not in taken)
+            rows += ps + [-1] * (b - len(ps))
+            idx += local + [next(pads) for _ in range(b - len(ps))]
+        return rows, np.asarray(idx, np.int32), b
+
+    def _scan_lanes(self, lanes, lane_chunks, lane_masks, row_sessions,
+                    scope: str, clock: "_ScanClock") -> int:
+        """Advance lanes ``lanes`` by their chunk lists -- the one scan
+        of all three flush paths.  The lanes are laid out as a lane
+        bucket (``_bucket_rows``); ``scan.gather`` takes the bucket's
+        states out of the lane table (on each device, under ``mesh=``),
+        ``_scan_batch`` runs its segments, ``scan.scatter`` writes the
+        states back.  Pad lanes carry all-masked chunks, so their states
+        return bit-identical.  The gather and the scatter are waited on
+        and add into ``clock``.  Returns the scan width."""
+        if not any(lane_chunks):
+            return 0
+        rows, idx, b = self._bucket_rows(lanes)
+        pick = (lambda xs, pad: [pad if r < 0 else xs[r] for r in rows])
+        target = (None if self._sharded is None
+                  else self._sharded.lane_sharding)
+        t0 = time.perf_counter_ns()
+        with self.obs.span("scan.gather", cat="scan", scope=scope,
+                           lanes=len(idx)):
+            idx = jax.device_put(idx, target)
+            sub = jax.block_until_ready(self._gather(self._states, idx))
+        gather_ns = time.perf_counter_ns() - t0
+        sub, width = self._scan_batch(
+            sub, pick(lane_chunks, []), pick(lane_masks, []),
+            pick(row_sessions, None), b, scope, clock)
+        t0 = time.perf_counter_ns()
+        with self.obs.span("scan.scatter", cat="scan", scope=scope,
+                           lanes=len(idx)):
+            self._states = jax.block_until_ready(
+                self._scatter(self._states, idx, sub))
+        clock.lanes(gather_ns + time.perf_counter_ns() - t0, len(idx),
+                    len(lanes))
+        return width
+
+    def _reset_group(self, lanes: List[int]) -> None:
+        """Reset lanes ``lanes`` to fresh state: one batched lane-init
+        per ``lanes_per_device`` lanes, its idx padded up to a lane
+        bucket by repeating a lane (resetting a lane twice is a no-op),
+        so the reset shapes are the lane buckets."""
+        cap = self._lane_buckets[-1]
+        for lo in range(0, len(lanes), cap):
+            part = lanes[lo:lo + cap]
+            part = part + [part[0]] * (self._lane_bucket(len(part))
+                                       - len(part))
+            states = self._reset_lanes(self._states,
+                                       np.asarray(part, np.int32))
+            self._states = (states if self._sharded is None
+                            else self._sharded.shard_states(states))
 
     def _scan_batch(self, state, lane_chunks, lane_masks, row_sessions,
-                    key: Tuple, run, target, scope: str,
-                    clock: "_ScanClock"):
-        """Advance ``state`` by every scan segment of one flush batch --
-        the segment loop of all three flush paths.  Each segment runs
-        three timed steps under its ``scan.segment`` span:
+                    bucket: int, scope: str, clock: "_ScanClock"):
+        """Advance the bucket ``state`` by every scan segment of one
+        flush batch.  Each segment runs three timed steps under its
+        ``scan.segment`` span:
 
         * ``scan.pack``: ``_pack_chunks``, the dense host batch;
-        * ``scan.h2d``: the batch copied to ``target`` (the device or
-          sharding the segment's executable takes; ``None`` is the
-          default device) and waited on;
+        * ``scan.h2d``: the batch copied to the device (split over the
+          lanes axis under ``mesh=``) and waited on;
         * ``scan.run``: the compiled scan plus the wait for its stats
           (``_apply_exec_stats``) -- device time as this thread sees it.
 
-        ``key`` + ``(width,)`` looks the executable up in the AOT table,
-        ``run`` is the jit fallback.  The step times add into ``clock``
-        whether tracing is on or off.  Returns ``(state, width)``."""
+        ``(bucket, width)`` looks the executable up in the AOT table;
+        ``self._run`` is the jit fallback.  The step times add into
+        ``clock`` whether tracing is on or off.  Returns
+        ``(state, width)``."""
         span = self.obs.span
+        target = (None if self._sharded is None
+                  else self._sharded.lane_sharding)
         width = 0
         for off, w in self._segments(lane_chunks):
             with span("scan.segment", cat="scan", scope=scope, offset=off,
@@ -890,7 +928,7 @@ class SessionEngine:
                         jax.device_put((chunks, mask), target))
                 t2 = time.perf_counter_ns()
                 with span("scan.run", cat="scan"):
-                    state, stats = self._aot.get(key + (w,), run)(
+                    state, stats = self._aot.get((bucket, w), self._run)(
                         state, chunks, mask)
                     self._apply_exec_stats(
                         stats, row_sessions,
@@ -899,11 +937,6 @@ class SessionEngine:
             clock.add(t1 - t0, t2 - t1, t3 - t2)
             width += w
         return state, width
-
-    def _group_target(self):
-        """Where a lane group's scan batch goes: the one device the
-        per-session tier scans on (``_gather_group``)."""
-        return None if self._sharded is None else self._sharded.group_device
 
     def _segments(self, lane_chunks):
         """Yield the ``(offset, width)`` scan segments covering the
@@ -931,14 +964,15 @@ class SessionEngine:
         """Pre-compile the whole AOT bucket table so steady-state
         traffic never retraces (requires ``aot_buckets=``).
 
-        AOT-lowers and compiles one executable per engine-wide scan
-        width (``jit(scan_lanes).lower().compile()``, sharded over the
-        mesh when distributed) and one per (lane-group bucket, width)
-        for the per-session tier, then primes every remaining
-        fixed-shape entry point (lane gather/scatter, merge, reset,
-        fold, the secondary scheduler) by executing it on scratch
-        states -- so ``flush`` / ``flush_session`` / ``query`` /
-        ``close`` are all compile-free afterwards.
+        For every lane bucket (``_lane_buckets``: 1, 2, 4, ... lanes per
+        device, up to ``lanes_per_device``) AOT-lowers and compiles one
+        scan executable per width (``jit(scan_lanes).lower().compile()``,
+        shard_map'd over the mesh when distributed) and primes the
+        bucket's gather, scatter and lane reset by executing them on
+        scratch states; then primes the remaining fixed-shape entry
+        points (merge, fold, the secondary scheduler) -- so ``flush`` /
+        ``flush_session`` / ``open_batch`` / ``query`` / ``close`` are
+        all compile-free afterwards.
 
         Needs the engine tuple dtype+shape: either call after the first
         ``append`` (``append`` triggers warmup automatically then), or
@@ -970,43 +1004,26 @@ class SessionEngine:
         scratch = (self._sharded.init_states() if self._sharded is not None
                    else core_executor.stack_states(self._fresh,
                                                    self.num_lanes))
-
-        def zeros(lanes, w):
-            zc = np.zeros((lanes, w, c, *feat), self._dtype)
-            zm = np.zeros((lanes, w, c), bool)
-            return zc, zm
-
-        for w in self._aot_widths:
-            zc, zm = zeros(self.num_lanes, w)
-            if self._sharded is not None:
-                zc = jax.device_put(zc, self._sharded.lane_sharding)
-                zm = jax.device_put(zm, self._sharded.lane_sharding)
-            self._aot[("eng", w)] = \
-                self._run_lanes.lower(scratch, zc, zm).compile()
-        # one executable per (lane-group bucket, width) serves BOTH the
-        # per-session flush tier and the admission-storm path: compiled
-        # executables key on argument shapes alone, so the two bucket
-        # families share the ("grp", b, w) table
-        for b in sorted({*self._group_buckets, *self._admit_buckets}):
-            idx = np.arange(b, dtype=np.int32)
-            sub = self._gather_group(scratch, idx)    # primes the gather
+        target = (None if self._sharded is None
+                  else self._sharded.lane_sharding)
+        n_dev = self.num_lanes // self.lanes_per_device
+        for b in self._lane_buckets:
+            idx = jax.device_put(np.tile(np.arange(b, dtype=np.int32), n_dev),
+                                 target)
+            sub = self._gather(scratch, idx)
             for w in self._aot_widths:
-                zc, zm = zeros(b, w)
-                self._aot[("grp", b, w)] = \
-                    self._run_group.lower(sub, zc, zm).compile()
-            self._scatter_group(scratch, idx, sub)    # primes the scatter
-        # batched lane-init shapes: close resets exact group sizes
-        # (1..1+secondary_slots); the storm lane-init pads its idx up to
-        # the admission bucket
-        for n in sorted({*range(1, 2 + self.secondary_slots),
-                         *self._admit_buckets}):
-            reset = self._reset_lanes(scratch, np.arange(n, dtype=np.int32))
+                zc = jax.ShapeDtypeStruct((n_dev * b, w, c, *feat),
+                                          self._dtype, sharding=target)
+                zm = jax.ShapeDtypeStruct((n_dev * b, w, c), np.bool_,
+                                          sharding=target)
+                self._aot[(b, w)] = self._run.lower(sub, zc, zm).compile()
+            self._scatter(scratch, idx, sub)
+            reset = self._reset_lanes(scratch, np.arange(b, dtype=np.int32))
             if self._sharded is not None:
                 self._sharded.shard_states(reset)
         # remaining fixed-shape entry points (query/close/re-grant): a
         # plain execution populates their jit caches
         self._merge_lane(scratch, 0)
-        self._reset_lane(scratch, 0)
         if self.secondary_slots and self.spec.merge is None:
             self._fold_lane(scratch, self.primary_slots, 0)
         self._res.merge_state(self._fresh)
@@ -1014,8 +1031,7 @@ class SessionEngine:
         d = compilemon.since(before)
         self._aot_info = {
             "widths": [int(w) for w in self._aot_widths],
-            "group_buckets": [int(b) for b in self._group_buckets],
-            "admit_buckets": [int(b) for b in self._admit_buckets],
+            "lane_buckets": [int(b) for b in self._lane_buckets],
             "n_executables": len(self._aot),
             "warmup_ms": round((time.perf_counter() - t0) * 1e3, 3),
             "warmup_compiles": int(d.n_compiles),
@@ -1023,22 +1039,14 @@ class SessionEngine:
         }
         return self._aot_info
 
-    def _gather_group(self, states, idx):
-        """Lanes ``idx`` of ``states`` as one stacked ``ExecState`` for
-        the per-session tier.  Under ``mesh=`` the group moves to one
-        device: its scan holds a Pallas kernel, which cannot be
-        partitioned across the mesh automatically."""
-        sub = self._take_lanes(states, idx)
-        return sub if self._sharded is None else \
-            self._sharded.to_group_device(sub)
-
-    def _scatter_group(self, states, idx, sub):
-        """Inverse of ``_gather_group``: ``states`` with lanes ``idx``
-        replaced by ``sub``, re-pinned to the lane sharding."""
-        if self._sharded is None:
-            return self._put_lanes(states, idx, sub)
-        states = self._put_lanes(states, idx, self._sharded.replicate(sub))
-        return self._sharded.shard_states(states)
+    def _secondary_groups(self) -> Dict[int, List[int]]:
+        """Primary slot -> the secondary lanes granted to it, for every
+        slot that holds a grant (one pass over the grant table)."""
+        groups: Dict[int, List[int]] = {}
+        for j, slot in enumerate(self._sec_assign.tolist()):
+            if slot >= 0:
+                groups.setdefault(slot, []).append(self.primary_slots + j)
+        return groups
 
     def _lane_group(self, slot: int) -> List[int]:
         """The lane ids a primary slot currently owns: its primary lane
@@ -1140,8 +1148,7 @@ class SessionEngine:
             masks.append(m)
         return chunks, masks
 
-    @staticmethod
-    def _pop_backlog(s: _Session, n: int) -> np.ndarray:
+    def _pop_backlog(self, s: _Session, n: int) -> np.ndarray:
         """Consume exactly ``n`` tuples off the backlog front: exhausted
         arrays pop left, a partially consumed head just advances
         ``backlog_off`` -- the unconsumed remainder is never copied."""
@@ -1160,6 +1167,9 @@ class SessionEngine:
                 s.backlog_off += need
                 need = 0
         s.backlog_tuples -= n
+        self._backlog_total -= n
+        if not s.backlog_tuples:
+            self._pending.discard(s.sid)
         return parts[0] if len(parts) == 1 else np.concatenate(parts, axis=0)
 
     # ------------------------------------------------------- slot scheduling
@@ -1184,10 +1194,19 @@ class SessionEngine:
         """Per-primary-slot pending chunk counts -- the workload histogram
         of the serving layer (sessions are the tuples, slots the PEs)."""
         out = np.zeros(self.primary_slots, np.float32)
-        for slot, sid in enumerate(self._slot_sid):
-            if sid is not None:
-                out[slot] = self.sessions[sid].backlog_tuples // self.chunk_size
+        for sid in self._pending:
+            s = self.sessions[sid]
+            if s.slot is not None:
+                out[s.slot] = s.backlog_tuples // self.chunk_size
         return out
+
+    def _index_backlog(self) -> None:
+        """Rebuild the host index of the work (``_pending``,
+        ``_backlog_total``) from the session table, after the table was
+        replaced wholesale (checkpoint restore)."""
+        live = [s for s in self.sessions.values() if not s.closed]
+        self._pending = {s.sid for s in live if s.backlog_tuples}
+        self._backlog_total = sum(s.backlog_tuples for s in live)
 
     def plan_secondary(self, backlog_chunks: np.ndarray) -> np.ndarray:
         """Greedy max-backlog splitting: ``scheduler.schedule_secpes`` over
@@ -1266,9 +1285,8 @@ class SessionEngine:
         if delta is not None:
             self._n_retraces += delta.n_compiles
             self._compile_stall_ms += delta.stall_ms
-        active = sum(sid is not None for sid in self._slot_sid)
-        backlog = sum(s.backlog_tuples for s in self.sessions.values()
-                      if not s.closed)
+        active = self.primary_slots - len(self._free_slots)
+        backlog = self._backlog_total
         row = {
             "flush": self._flush_no,
             "scope": scope,
@@ -1335,23 +1353,30 @@ class SessionEngine:
         if now - self._gauge_scan_last < self._GAUGE_SCAN_S:
             return
         self._gauge_scan_last = now
-        busy = {slot for slot, sid in enumerate(self._slot_sid)
-                if sid is not None}
-        busy |= {self.primary_slots + j
-                 for j in range(self.secondary_slots)
-                 if self._sec_assign[j] >= 0}
-        m.lanes_busy.set(len(busy))
+        n_sec = int((self._sec_assign >= 0).sum())
+        m.lanes_busy.set(self.primary_slots - len(self._free_slots) + n_sec)
         if self.num_lanes <= m.MAX_LANE_SERIES:
+            busy = {slot for slot, sid in enumerate(self._slot_sid)
+                    if sid is not None}
+            busy |= {self.primary_slots + j
+                     for j in range(self.secondary_slots)
+                     if self._sec_assign[j] >= 0}
             for ln in range(self.num_lanes):
                 m.occupancy.set(1.0 if ln in busy else 0.0, lane=str(ln))
+        # only sessions holding backlog have depth; a tenant whose depth
+        # gauge was shown and has drained reads 0
         depth: Dict[str, int] = {}
-        for sid in self._slot_sid:
-            if sid is not None:
-                s = self.sessions[sid]
+        for sid in self._pending:
+            s = self.sessions[sid]
+            if s.slot is not None:
                 depth[s.tenant] = depth.get(s.tenant, 0) + s.backlog_tuples
-        tenants = sorted(depth, key=lambda t: (-depth[t], t))
-        for tenant in tenants[:m.MAX_TENANT_SERIES]:
+        shown = set(sorted(depth, key=lambda t: (-depth[t], t))
+                    [:m.MAX_TENANT_SERIES])
+        for tenant in self._depth_shown - shown:
+            m.backlog.set(0, tenant=tenant)
+        for tenant in shown:
             m.backlog.set(depth[tenant], tenant=tenant)
+        self._depth_shown = shown
 
     # ------------------------------------------------------- live load views
 

@@ -18,7 +18,7 @@ REPO = Path(__file__).resolve().parent.parent
 if str(REPO) not in sys.path:         # benchmarks/ is a repo-root package
     sys.path.insert(0, str(REPO))
 
-from repro.apps import histo
+from repro.apps import histo, hll
 from repro.core import make_executor
 from repro.data.pipeline import chunk_stream
 from repro.serve import SessionEngine, StreamEngine
@@ -30,6 +30,29 @@ BINS, DOMAIN = 64, 1 << 16
 
 def _oracle(keys: np.ndarray) -> np.ndarray:
     return histo.oracle(np.asarray(keys), BINS, DOMAIN, SMALL_M)
+
+
+HLL_P = 8            # 256 registers, 32 per PE at SMALL_M
+
+
+def _hll_oracle(keys: np.ndarray) -> np.ndarray:
+    return hll.oracle(np.asarray(keys), HLL_P, SMALL_M)
+
+
+class _App:
+    """One app under the app-agnostic bit-exact tests: its spec and its
+    numpy oracle over the key column."""
+
+    def __init__(self, spec, oracle):
+        self.spec, self.oracle = spec, oracle
+
+
+@pytest.fixture(scope="module", params=["histo", "hll"])
+def app(request, small_spec):
+    """HISTO (``add`` combine) and HLL (``max`` combine) in turn."""
+    if request.param == "histo":
+        return _App(small_spec, _oracle)
+    return _App(hll.make_spec(HLL_P, SMALL_M), _hll_oracle)
 
 
 def _solo(spec, data: np.ndarray) -> np.ndarray:
@@ -115,13 +138,13 @@ def _session_engine(spec, **kw):
 class TestSessionEngine:
     @pytest.mark.parametrize("alpha", [0.0, 1.5])
     @pytest.mark.parametrize("ragged", [False, True])
-    def test_bit_exact_vs_one_shot(self, small_spec, zipf_dataset, alpha,
+    def test_bit_exact_vs_one_shot(self, app, zipf_dataset, alpha,
                                    ragged):
         """Acceptance: SessionEngine == one-shot executor on the same
         tuples, for any append chunking, with and without ragged tails."""
         n = 6 * SMALL_CHUNK + (137 if ragged else 0)
         data = zipf_dataset(n, DOMAIN, alpha)
-        eng = _session_engine(small_spec)
+        eng = _session_engine(app.spec)
         sid = eng.open()
         rng = np.random.default_rng(0)
         i = 0
@@ -132,17 +155,17 @@ class TestSessionEngine:
             if rng.random() < 0.5:
                 eng.flush()
         merged, _ = eng.close(sid)
-        np.testing.assert_array_equal(merged, _solo(small_spec, data))
-        np.testing.assert_array_equal(merged, _oracle(data[:, 0]))
+        np.testing.assert_array_equal(merged, _solo(app.spec, data))
+        np.testing.assert_array_equal(merged, app.oracle(data[:, 0]))
 
-    def test_ragged_append_equivalence(self, small_spec, zipf_dataset):
+    def test_ragged_append_equivalence(self, app, zipf_dataset):
         """Any partition of the same stream into appends yields identical
         merged buffers (mid-stream queries included)."""
         data = zipf_dataset(3 * SMALL_CHUNK + 41, DOMAIN, 1.5)
         results = []
         for cuts in ([len(data)], [100, 1, 333, len(data) - 434],
                      [SMALL_CHUNK] * 3 + [41]):
-            eng = _session_engine(small_spec)
+            eng = _session_engine(app.spec)
             sid = eng.open()
             i = 0
             for c in cuts:
@@ -150,7 +173,7 @@ class TestSessionEngine:
                 i += c
             assert i == len(data)
             snap = eng.query(sid)        # mid-stream snapshot is complete
-            np.testing.assert_array_equal(snap, _oracle(data[:, 0]))
+            np.testing.assert_array_equal(snap, app.oracle(data[:, 0]))
             merged, _ = eng.close(sid)
             results.append(merged)
         for r in results[1:]:
@@ -288,7 +311,7 @@ class TestSessionEngine:
 
 # --------------------------------------- per-session flush (latency tier)
 class TestPerSessionFlush:
-    def test_query_scopes_identical_results(self, small_spec, zipf_dataset):
+    def test_query_scopes_identical_results(self, app, zipf_dataset):
         """Acceptance: the per-session flush tier returns results
         identical to the engine-wide flush, for every tenant, with
         pending backlog on BOTH."""
@@ -296,7 +319,7 @@ class TestPerSessionFlush:
                                     0.7 * t, seed=t) for t in range(2)}
         snaps = {}
         for scope in ("session", "engine"):
-            eng = _session_engine(small_spec)
+            eng = _session_engine(app.spec)
             sids = {t: eng.open() for t in datasets}
             for t, d in datasets.items():
                 eng.append(sids[t], d)
@@ -306,7 +329,7 @@ class TestPerSessionFlush:
             np.testing.assert_array_equal(snaps["session"][t],
                                           snaps["engine"][t])
             np.testing.assert_array_equal(snaps["session"][t],
-                                          _oracle(d[:, 0]))
+                                          app.oracle(d[:, 0]))
 
     def test_session_flush_leaves_other_backlogs(self, small_spec,
                                                  zipf_dataset):
@@ -401,15 +424,15 @@ class TestSessionEngineMesh1:
     def _mesh(self):
         return jax.make_mesh((1,), ("lanes",))
 
-    def test_scenario_bit_exact_vs_unsharded(self, small_spec,
+    def test_scenario_bit_exact_vs_unsharded(self, app,
                                              zipf_dataset):
         datasets = {t: zipf_dataset(3 * SMALL_CHUNK + 41 * t, DOMAIN,
                                     (0.0, 1.5)[t % 2], seed=t)
                     for t in range(3)}
         got = _drive_scenario(
-            _session_engine(small_spec, primary_slots=3,
+            _session_engine(app.spec, primary_slots=3,
                             mesh=self._mesh()), datasets)
-        want = _drive_scenario(_session_engine(small_spec, primary_slots=3),
+        want = _drive_scenario(_session_engine(app.spec, primary_slots=3),
                                datasets)
         assert got.keys() == want.keys()
         for k in want:
@@ -417,14 +440,14 @@ class TestSessionEngineMesh1:
                                           np.asarray(want[k]))
         for t, d in datasets.items():
             np.testing.assert_array_equal(np.asarray(got[f"c{t}"]),
-                                          _oracle(d[:, 0]))
+                                          app.oracle(d[:, 0]))
 
-    def test_regrant_folds_bit_exact(self, small_spec, zipf_dataset):
+    def test_regrant_folds_bit_exact(self, app, zipf_dataset):
         """Alternating hot tenants force secondary re-grants (the
         collective §IV-B fold path) on the meshed engine; results and
         re-grant counters match the unsharded engine exactly."""
-        engines = {"mesh": _session_engine(small_spec, mesh=self._mesh()),
-                   "local": _session_engine(small_spec)}
+        engines = {"mesh": _session_engine(app.spec, mesh=self._mesh()),
+                   "local": _session_engine(app.spec)}
         results = {}
         for name, eng in engines.items():
             d = {t: np.zeros((0, 2), np.int32) for t in range(2)}
@@ -490,10 +513,10 @@ class TestTenantSkewScheduling:
             if backlog.max() >= eng.min_grant_chunks:
                 assert (backlog / shares).max() <= backlog.max() + 1e-6
 
-    def test_regrants_keep_exactness(self, small_spec, zipf_dataset):
+    def test_regrants_keep_exactness(self, app, zipf_dataset):
         """Secondary lanes migrate between tenants across flushes (the
         lifted merge-before-reassign); results stay exact for both."""
-        eng = _session_engine(small_spec, primary_slots=2,
+        eng = _session_engine(app.spec, primary_slots=2,
                               secondary_slots=2)
         d = {t: np.zeros((0, 2), np.int32) for t in range(2)}
         sids = {t: eng.open() for t in range(2)}
@@ -510,7 +533,7 @@ class TestTenantSkewScheduling:
         assert eng._slot_reschedules > 0     # grants really moved
         for t in range(2):
             merged, stats = eng.close(sids[t])
-            np.testing.assert_array_equal(merged, _oracle(d[t][:, 0]))
+            np.testing.assert_array_equal(merged, app.oracle(d[t][:, 0]))
             if t == 0:
                 assert stats["sec_lane_flushes"] > 0
 
@@ -535,16 +558,16 @@ class TestAOTBuckets:
                                 (0.0, 1.5)[t % 2], seed=t)
                 for t in range(n)}
 
-    def test_bit_exact_vs_unbucketed_local(self, small_spec, zipf_dataset):
+    def test_bit_exact_vs_unbucketed_local(self, app, zipf_dataset):
         """Acceptance: same ragged multi-tenant scenario through the
         plain-jit and the aot_buckets=2 engine (width chopping active:
         backlogs run to 5+ chunks) -- every query/close answer
         identical, and exact vs the oracle."""
         datasets = self._datasets(zipf_dataset)
         want = _drive_scenario(
-            _session_engine(small_spec, primary_slots=3), datasets)
+            _session_engine(app.spec, primary_slots=3), datasets)
         got = _drive_scenario(
-            _session_engine(small_spec, primary_slots=3, aot_buckets=2),
+            _session_engine(app.spec, primary_slots=3, aot_buckets=2),
             datasets)
         assert got.keys() == want.keys()
         for k in want:
@@ -552,17 +575,17 @@ class TestAOTBuckets:
                                           np.asarray(want[k]))
         for t, d in datasets.items():
             np.testing.assert_array_equal(np.asarray(got[f"c{t}"]),
-                                          _oracle(d[:, 0]))
+                                          app.oracle(d[:, 0]))
 
-    def test_bit_exact_vs_unbucketed_mesh_of_1(self, small_spec,
+    def test_bit_exact_vs_unbucketed_mesh_of_1(self, app,
                                                zipf_dataset):
         """Acceptance: the bucketed MESH engine (warmup lowers the
         shard_map'd executables) answers identically to the plain local
         engine on the same scenario."""
         datasets = self._datasets(zipf_dataset, n=2)
-        want = _drive_scenario(_session_engine(small_spec), datasets)
+        want = _drive_scenario(_session_engine(app.spec), datasets)
         got = _drive_scenario(
-            _session_engine(small_spec, aot_buckets=4,
+            _session_engine(app.spec, aot_buckets=4,
                             mesh=jax.make_mesh((1,), ("lanes",))),
             datasets)
         for k in want:
@@ -572,14 +595,15 @@ class TestAOTBuckets:
     def test_zero_retraces_after_warmup(self, small_spec, zipf_dataset):
         """Regression: after the (append-triggered) warmup, a ragged
         multi-tenant scenario -- widths crossing the W=2 bucket cap,
-        lane groups crossing group buckets, both flush tiers -- records
-        ZERO retraces in the per-flush telemetry."""
+        active-lane counts crossing lane buckets, both flush tiers --
+        records ZERO retraces in the per-flush telemetry."""
         eng = _session_engine(small_spec, aot_buckets=2)
         sid = eng.open()
         eng.append(sid, zipf_dataset(8, DOMAIN, 1.5))  # triggers warmup
         eng.close(sid)
         aot = eng.telemetry_record()["extra"]["aot"]
         assert aot is not None and aot["widths"] == [1, 2]
+        assert aot["lane_buckets"] == [1, 2, 4]      # the one family
         assert aot["warmup_compiles"] > 0
         n0 = len(eng.telemetry_record()["rows"])
         _drive_scenario(eng, self._datasets(zipf_dataset, n=2))
@@ -588,6 +612,7 @@ class TestAOTBuckets:
         assert steady, "scenario recorded no flushes"
         bad = [r for r in steady if r["n_retraces"]]
         assert not bad, bad
+        assert {r["scan_lanes"] for r in steady} <= {0, 1, 2, 4}
         # width chopping: a >W-chunk backlog flushes in one go, still
         # compile-free (W-wide segments through the bucket table)
         wide = zipf_dataset(5 * SMALL_CHUNK + 9, DOMAIN, 1.5, seed=99)
@@ -604,12 +629,12 @@ class TestAOTBuckets:
         assert rec["extra"]["config"]["aot_buckets"] == 2
 
     def test_group_padding_leaves_other_sessions_untouched(
-            self, small_spec, zipf_dataset):
+            self, app, zipf_dataset):
         """A per-session flush whose lane group rounds UP to a bucket
         pads with another session's lane carrying all-masked chunks;
         both sessions must stay exact (the padded lane's state rides
         through the scan bit-identically)."""
-        eng = _session_engine(small_spec, primary_slots=2,
+        eng = _session_engine(app.spec, primary_slots=2,
                               secondary_slots=3, aot_buckets=2)
         sids = [eng.open(), eng.open()]
         d0 = zipf_dataset(10 * SMALL_CHUNK + 13, DOMAIN, 1.5, seed=1)
@@ -624,9 +649,9 @@ class TestAOTBuckets:
             eng.flush_session(sids[0])
             d0 = np.concatenate([d0, tail0])
         np.testing.assert_array_equal(
-            np.asarray(eng.query(sids[0])), _oracle(d0[:, 0]))
+            np.asarray(eng.query(sids[0])), app.oracle(d0[:, 0]))
         np.testing.assert_array_equal(
-            np.asarray(eng.query(sids[1])), _oracle(d1[:, 0]))
+            np.asarray(eng.query(sids[1])), app.oracle(d1[:, 0]))
 
     def test_warmup_validation_and_knobs(self, small_spec):
         with pytest.raises(ValueError, match="aot_buckets"):
@@ -695,7 +720,7 @@ class TestBatchedAdmission:
         return answers
 
     @pytest.mark.parametrize("mode", ["local", "mesh1"])
-    def test_bit_exact_vs_serial_admission(self, small_spec, zipf_dataset,
+    def test_bit_exact_vs_serial_admission(self, app, zipf_dataset,
                                            mode):
         """Acceptance: the SAME over-capacity storm (7 sessions, 3
         primary slots) through open_batch and through serial
@@ -709,9 +734,9 @@ class TestBatchedAdmission:
         tails = [zipf_dataset(SMALL_CHUNK + 31 * i, DOMAIN, 1.0,
                               seed=100 + i) for i in range(7)]
 
-        batch = _session_engine(small_spec, **kw)
+        batch = _session_engine(app.spec, **kw)
         sids_b = batch.open_batch(tenants, first=firsts)
-        serial = _session_engine(small_spec, **kw)
+        serial = _session_engine(app.spec, **kw)
         sids_s = []
         for t, f in zip(tenants, firsts):
             sid = serial.open(t)
@@ -730,7 +755,7 @@ class TestBatchedAdmission:
         for sid, first, tail in zip(sids_b, firsts, tails):
             keys = (tail[:, 0] if first is None
                     else np.concatenate([first[:, 0], tail[:, 0]]))
-            np.testing.assert_array_equal(got[sid], _oracle(keys))
+            np.testing.assert_array_equal(got[sid], app.oracle(keys))
 
     def test_fifo_overflow_and_drain_deterministic(self, small_spec):
         """Satellite: the waitlist is STRICTLY FIFO by open/open_batch
@@ -826,10 +851,11 @@ class TestBatchedAdmission:
 
 
 class TestWarmupTableCompleteness:
-    """Satellite: every width/group shape the engine can LEGALLY produce
-    -- pow2 scan segments, capped lane-group buckets, admission buckets
-    -- is in the compiled table, and nothing else is; the zero-retrace
-    asserts above cannot pass vacuously against an empty table."""
+    """Satellite: every (lane bucket, width) shape the engine can LEGALLY
+    produce -- pow2 scan segments, the one lane-bucket family that the
+    engine-wide, per-session and admission scans share -- is in the
+    compiled table, and nothing else is; the zero-retrace asserts above
+    cannot pass vacuously against an empty table."""
 
     @pytest.mark.parametrize("primary_slots,secondary_slots,aot_buckets",
                              [(2, 2, 2), (3, 1, 4), (5, 0, 1), (1, 3, 8)])
@@ -843,29 +869,127 @@ class TestWarmupTableCompleteness:
         # legal widths: _segments only ever yields pow2 widths <= cap
         assert widths == tuple(sorted(widths))
         assert all(w & (w - 1) == 0 for w in widths)
-        # enumerate every shape the runtime paths can present
-        legal = {("eng", w) for w in widths}
-        for g in range(1, 2 + secondary_slots):        # flush_session groups
-            legal |= {("grp", eng._group_bucket(g), w) for w in widths}
-        for k in range(1, 1 + primary_slots):          # admission storms
-            legal |= {("grp", eng._admit_bucket(k), w) for w in widths}
+        # every lane count a flush can present: 1..num_lanes active lanes
+        buckets = {eng._lane_bucket(n) for n in range(1, 1 + eng.num_lanes)}
+        assert buckets == set(eng._lane_buckets)
+        assert max(buckets) == eng.num_lanes
+        legal = {(b, w) for b in buckets for w in widths}
         assert set(eng._aot) == legal
         assert eng._aot_info["n_executables"] == len(legal)
-        # the info dict advertises the same bucket families
-        assert set(eng._aot_info["group_buckets"]) == \
-            {eng._group_bucket(g) for g in range(1, 2 + secondary_slots)}
-        assert set(eng._aot_info["admit_buckets"]) == \
-            {eng._admit_bucket(k) for k in range(1, 1 + primary_slots)}
+        # the info dict advertises the same bucket family
+        assert set(eng._aot_info["lane_buckets"]) == buckets
+        # every layout of every active-lane set lands in the family
+        for n in range(1, 1 + eng.num_lanes):
+            rows, idx, b = eng._bucket_rows(list(range(n)))
+            assert b in buckets and len(idx) == len(rows) == b
+            assert sorted(r for r in rows if r >= 0) == list(range(n))
+            assert len(set(idx.tolist())) == b
 
     def test_every_segment_width_hits_the_table(self, small_spec):
-        """Property: for ANY backlog width 1..6*W the pow2 segments
-        ``_segments`` yields are all present as ("eng", w) keys -- no
-        legal flush can fall through to a fresh trace."""
+        """Property: for ANY backlog width 1..6*W and ANY lane bucket the
+        pow2 segments ``_segments`` yields are all present as (bucket,
+        width) keys -- no legal flush can fall through to a fresh
+        trace."""
         eng = _session_engine(small_spec, aot_buckets=2)
         eng.warmup(dtype=np.int64, feat_shape=(2,))
         cap = eng._aot_widths[-1]
         for wmax in range(1, 6 * cap + 1):
             segs = list(eng._segments([list(range(wmax))]))
             assert sum(w for _, w in segs) >= wmax
-            for _, w in segs:
-                assert ("eng", w) in eng._aot, (wmax, w)
+            for b in eng._lane_buckets:
+                for _, w in segs:
+                    assert (b, w) in eng._aot, (wmax, b, w)
+
+
+# ------------------------------- HLL through the active-lane flush paths
+def _hll_many_tenant_run(mesh=None):
+    """HyperLogLog (the ``max`` combine) over 12 tenants on 16 lanes, few
+    of them active per flush, through every flush path: an
+    ``open_batch`` storm with first appends, engine-wide flushes of a
+    hot and a warm tenant (the hot one alternating, so secondary lanes
+    are re-granted and folded with ``max``), forced engine-wide flushes
+    and per-session flushes behind ``query``, then every close.  Asserts
+    each answer register for register against ``apps/hll.oracle``, that
+    each engine-wide flush scanned fewer lanes than the table holds, and
+    that nothing compiled after the warmup.  Importable by a child
+    process that holds a multi-device CPU mesh."""
+    from repro.data import zipf
+    eng = SessionEngine(hll.make_spec(HLL_P, SMALL_M), num_pri=SMALL_M,
+                        num_sec=2, chunk_size=SMALL_CHUNK, primary_slots=14,
+                        secondary_slots=2, aot_buckets=2, mesh=mesh)
+    keys = {t: np.zeros(0, np.int32) for t in range(12)}
+
+    def feed(t, n, seed):
+        d = zipf.zipf_tuples(n, DOMAIN, 1.5, seed=seed)
+        keys[t] = np.concatenate([keys[t], d[:, 0]])
+        return d
+
+    def check(t, got):
+        np.testing.assert_array_equal(np.asarray(got), _hll_oracle(keys[t]))
+
+    firsts = [None] * 12
+    for t, n in ((0, 2 * SMALL_CHUNK + 5), (5, 77), (9, SMALL_CHUNK)):
+        firsts[t] = feed(t, n, 100 + t)
+    sids = eng.open_batch([f"t{t}" for t in range(12)], first=firsts)
+    n0 = len(eng.telemetry_record()["rows"])
+    for r in range(6):
+        hot, warm = (0, 3)[r % 2], 6 + r % 3
+        eng.append(sids[hot], feed(hot, 5 * SMALL_CHUNK + 13 * r, 10 * r))
+        eng.append(sids[warm], feed(warm, SMALL_CHUNK + 7, 10 * r + 1))
+        eng.flush()
+        if r % 2:
+            check(hot, eng.query(sids[hot]))          # per-session tier
+        else:
+            eng.append(sids[5], feed(5, 31 + r, 10 * r + 2))
+            eng.flush(force=(sids[hot], sids[5]))     # engine-wide, forced
+            check(5, eng._snapshot(eng.sessions[sids[5]]))
+            check(hot, eng._snapshot(eng.sessions[sids[hot]]))
+    assert eng.slot_reschedules > 0                   # max folds ran
+    rows = eng.telemetry_record()["rows"][n0:]
+    engine_rows = [r for r in rows if r["scope"] == "engine"
+                   and r["scan_lanes"]]
+    assert engine_rows
+    n_dev = eng.num_lanes // eng.lanes_per_device
+    for row in engine_rows:
+        assert row["active_lanes"] <= row["scan_lanes"] < eng.num_lanes
+        assert row["scan_lanes"] % n_dev == 0
+        assert row["scan_lanes"] // n_dev in eng._lane_buckets
+    assert not [r for r in rows if r["n_retraces"]]
+    sec = 0
+    for t in range(12):
+        merged, stats = eng.close(sids[t])
+        check(t, merged)
+        sec += stats["sec_lane_flushes"]
+    assert sec > 0
+    return {"engine_flushes": len(engine_rows),
+            "scan_lanes": sorted({r["scan_lanes"] for r in engine_rows})}
+
+
+class TestHLLActiveLaneFlush:
+    """HLL through the engine's three flush paths with few active lanes,
+    register for register against the oracle: on one device, on a mesh
+    of one, and on a four-device CPU mesh in a child process (each
+    device gathers its own active lanes)."""
+
+    def test_one_device(self):
+        out = _hll_many_tenant_run()
+        assert out["engine_flushes"] >= 6
+
+    def test_mesh_of_1(self):
+        _hll_many_tenant_run(jax.make_mesh((1,), ("lanes",)))
+
+    def test_four_device_mesh(self, cpu_mesh_env):
+        import subprocess
+        code = (
+            "import sys; sys.path[:0] = [%r, %r]\n"
+            "from repro.core.distributed import lane_mesh\n"
+            "from tests.test_serving import _hll_many_tenant_run\n"
+            "print('RESULT', _hll_many_tenant_run(lane_mesh(4)))\n"
+            % (str(REPO), str(REPO / "src")))
+        env = dict(cpu_mesh_env,
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4")
+        r = subprocess.run([sys.executable, "-c", code], env=env,
+                           capture_output=True, text=True, timeout=600,
+                           cwd=str(REPO))
+        assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
+        assert "RESULT" in r.stdout

@@ -4,8 +4,10 @@ The TPU compiler is installed next to JAX, so these tests compile for a
 described ``v5e:2x2`` chip (nothing runs): ``route_accumulate`` for add
 and max in int32 and float32 and ``cms_update`` at the widths the
 serving smoke uses, plus one vmapped histogram lane-scan step the way
-``SessionEngine`` builds it.  Interpret-mode tests cannot see what these
-catch: Mosaic refusing a layout, an int32 matmul or too much memory.
+``SessionEngine`` builds it and one HyperLogLog lane bucket of the
+hll-16k deployment (gather, scan, scatter).  Interpret-mode tests
+cannot see what these catch: Mosaic refusing a layout, an int32 matmul
+or too much memory.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, and every pytest
@@ -18,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.apps import histo
+from repro.apps import histo, hll
 from repro.core import executor as core_executor
 from repro.kernels import dispatch
 from repro.kernels.cms_update import cms_update
@@ -85,3 +87,36 @@ def test_histo_lane_scan_compiles(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < V5E_HBM
+
+
+def test_hll_lane_bucket_compiles(one_chip):
+    """One engine-wide flush of the hll-16k deployment, the way the
+    active-lane flush runs it: an 8-lane bucket gathered out of the
+    16,448-lane table of p = 14 registers, scanned with the native
+    ``max`` kernel, scattered back -- each program within one chip."""
+    lanes, bucket = 16448, 8
+    spec = hll.make_spec(14, 16)
+    res = core_executor.make_resumable_executor(
+        spec, 16, 4, T, kernel_backend=dispatch.PALLAS)
+
+    def states(n):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda: core_executor.stack_states(
+                res.init_state(), n)))
+
+    table, sub = states(lanes), states(bucket)
+    idx = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
+    chunks = jax.ShapeDtypeStruct((bucket, 1, T, 2), jnp.int32,
+                                  sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((bucket, 1, T), jnp.bool_,
+                                sharding=one_chip)
+    compiled = [
+        jax.jit(core_executor.take_lanes).lower(table, idx).compile(),
+        _compile(res.scan_lanes, sub, chunks, mask),
+        jax.jit(core_executor.put_lanes).lower(table, idx, sub).compile()]
+    for c in compiled:
+        mem = c.memory_analysis()
+        assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes) < V5E_HBM
