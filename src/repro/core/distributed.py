@@ -343,23 +343,32 @@ def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
 
     fold_lane = None
     if res.spec.merge is None:        # decomposable buffers only (add/max)
+        def local(i):
+            """Local index of global lane ``i`` on this shard (0 where
+            the shard does not hold it) and whether it holds it."""
+            base = jax.lax.axis_index(axis) * lanes_per_device
+            own = (i >= base) & (i < base + lanes_per_device)
+            return jnp.where(own, i - base, 0), own
+
         def _fold(states, src, dst):
-            gid = local_ids()
             # src's merged contribution, delivered to every shard: the
             # §IV-B merge-before-reassign expressed as a collective
-            contrib = jax.lax.psum(merge_selected(states, gid == src), axis)
-            own = (gid == dst).reshape((-1,) + (1,) * contrib.ndim)
+            contrib = jax.lax.psum(
+                merge_selected(states, local_ids() == src), axis)
+            # each write touches one lane (unchanged off its owner), so
+            # XLA updates the table in place when the caller donates it
+            d, own = local(dst)
             bufs = states.buffers                    # [L, M+X, *local]
             m = res.num_pri
-            if res.spec.combine == "add":
-                bufs = bufs.at[:, :m].add(jnp.where(own, contrib, 0))
-            else:
-                neutral = (jnp.iinfo(bufs.dtype).min
-                           if jnp.issubdtype(bufs.dtype, jnp.integer)
-                           else -jnp.inf)
-                bufs = bufs.at[:, :m].max(jnp.where(own, contrib, neutral))
+            row = bufs[d, :m]
+            comb = (row + contrib if res.spec.combine == "add"
+                    else jnp.maximum(row, contrib))
+            bufs = bufs.at[d, :m].set(jnp.where(own, comb, row))
             states = dataclasses.replace(states, buffers=bufs)
-            return set_lane(states, gid == src, fresh)
+            s, own = local(src)
+            return jax.tree.map(
+                lambda x, f: x.at[s].set(jnp.where(own, f, x[s])),
+                states, fresh)
 
         fold_lane = jax.jit(jax.shard_map(
             _fold, mesh=mesh, in_specs=(pspec, P(), P()), out_specs=pspec))

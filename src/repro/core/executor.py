@@ -443,8 +443,28 @@ def take_lanes(states: ExecState, idx) -> ExecState:
 
 def put_lanes(states: ExecState, idx, sub: ExecState) -> ExecState:
     """Scatter lane sub-states back into a lanes-stacked ``ExecState``
-    (inverse of ``take_lanes``)."""
-    return jax.tree.map(lambda x, s: x.at[idx].set(s), states, sub)
+    (inverse of ``take_lanes``; the ids ``idx`` are distinct)."""
+    return _write_lanes(states, idx,
+                        lambda k: jax.tree.map(lambda s: s[k], sub))
+
+
+def reset_lanes(states: ExecState, idx, fresh: ExecState) -> ExecState:
+    """Overwrite lanes ``idx`` of a lanes-stacked ``ExecState`` with the
+    one-lane state ``fresh`` (an id may repeat)."""
+    return _write_lanes(states, idx, lambda k: fresh)
+
+
+def _write_lanes(states: ExecState, idx, lane) -> ExecState:
+    """Write ``lane(k)`` (a one-lane state) into lane ``idx[k]`` for each
+    ``k``, one dynamic update per lane.  A table the caller donates is
+    then written in place whatever its device layout: a multi-lane
+    scatter makes the TPU lay the whole table out lane-major and back
+    (two copies of it) around the write."""
+    def write(k, st):
+        return jax.tree.map(
+            lambda x, v: jax.lax.dynamic_update_index_in_dim(
+                x, v.astype(x.dtype), idx[k], 0), st, lane(k))
+    return jax.lax.fori_loop(0, len(idx), write, states)
 
 
 def make_multistream_executor(

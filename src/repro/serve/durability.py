@@ -602,9 +602,10 @@ class DurableSessionEngine(SessionEngine):
                     ck_step = int(meta["step"])
                     idx = jnp.arange(self.num_lanes, dtype=jnp.int32)
                     lanes = jax.tree.map(jnp.asarray, ck["lanes"])
-                    states = self._put_lanes(self._states, idx, lanes)
-                    self._states = (states if self._sharded is None
-                                    else self._sharded.shard_states(states))
+                    self._update_table(self._put_lanes, idx, lanes)
+                    if self._sharded is not None:
+                        self._states = self._sharded.shard_states(
+                            self._states)
             if self._aot_widths and self._dtype is not None:
                 # land the restored engine in the same buckets BEFORE the
                 # WAL tail replays: replayed appends/flushes must not

@@ -136,7 +136,9 @@ Durability (DESIGN.md §10, docs/durability.md)
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
+import re
 import time
 from collections import deque
 from typing import Any, Deque, Dict, Iterable, List, Optional, Tuple
@@ -200,14 +202,18 @@ class _ScanClock:
     the telemetry row's ``pack_ms`` / ``h2d_ms`` / ``run_ms`` /
     ``segments`` columns, ``gather_ms`` (the lane bucket's gather plus
     scatter), ``scan_lanes`` (the lanes the scan presented, pads
-    included) and ``active_lanes`` (the lanes that carried a chunk)."""
+    included), ``active_lanes`` (the lanes that carried a chunk) and
+    ``table_updates`` (the lane-table scatter, fold and reset
+    dispatches of the flush: the engine's count, ``table_updates0`` at
+    its start, read again by ``columns``)."""
 
     __slots__ = ("pack_ns", "h2d_ns", "run_ns", "segments", "gather_ns",
-                 "scan_lanes", "active_lanes")
+                 "scan_lanes", "active_lanes", "table_updates0")
 
-    def __init__(self):
+    def __init__(self, table_updates0: int):
         self.pack_ns = self.h2d_ns = self.run_ns = self.segments = 0
         self.gather_ns = self.scan_lanes = self.active_lanes = 0
+        self.table_updates0 = table_updates0
 
     def add(self, pack_ns: int, h2d_ns: int, run_ns: int) -> None:
         self.pack_ns += pack_ns
@@ -221,14 +227,15 @@ class _ScanClock:
         self.scan_lanes += scan_lanes
         self.active_lanes += active_lanes
 
-    def columns(self) -> Dict[str, Any]:
+    def columns(self, table_updates: int) -> Dict[str, Any]:
         return {"pack_ms": round(self.pack_ns / 1e6, 3),
                 "h2d_ms": round(self.h2d_ns / 1e6, 3),
                 "run_ms": round(self.run_ns / 1e6, 3),
                 "segments": self.segments,
                 "gather_ms": round(self.gather_ns / 1e6, 3),
                 "scan_lanes": self.scan_lanes,
-                "active_lanes": self.active_lanes}
+                "active_lanes": self.active_lanes,
+                "table_updates": table_updates - self.table_updates0}
 
 
 class _EngineMetrics:
@@ -400,9 +407,15 @@ class SessionEngine:
         self._sharded = None
         # every flush path scans a BUCKET of lanes: the lanes that carry
         # a chunk, gathered (per device under ``mesh=``) into a
-        # power-of-two lane count, scanned, scattered back
+        # power-of-two lane count, scanned, scattered back.  The engine
+        # owns the lane table, so each program that returns an updated
+        # table (scatter, fold, reset) takes it DONATED: XLA writes the
+        # touched lanes in place instead of copying the whole table
+        # first, and the old table is deleted -- every caller rebinds
+        # ``self._states`` to the result (``_update_table``)
+        donated = functools.partial(jax.jit, donate_argnums=0)
         self._take_lanes = jax.jit(core_executor.take_lanes)
-        self._put_lanes = jax.jit(core_executor.put_lanes)
+        self._put_lanes = donated(core_executor.put_lanes)
         if mesh is not None:
             from repro.core import distributed as core_distributed
             self._sharded = core_distributed.make_lane_sharded_executor(
@@ -410,11 +423,11 @@ class SessionEngine:
             self.lanes_per_device = self._sharded.lanes_per_device
             self._states = self._sharded.init_states()
             self._gather = self._sharded.take_local
-            self._scatter = self._sharded.put_local
+            self._scatter = donated(self._sharded.put_local)
             self._run = self._sharded.run_lanes
             self._merge_lane = self._sharded.merge_lane
             if spec.merge is None:
-                self._fold_lane = self._sharded.fold_lane
+                self._fold_lane = donated(self._sharded.fold_lane)
         else:
             self.lanes_per_device = self.num_lanes
             self._states = core_executor.stack_states(fresh, self.num_lanes)
@@ -425,14 +438,15 @@ class SessionEngine:
                 lambda states, i: self._res.merge_state(
                     jax.tree.map(lambda x: x[i], states)))
             if spec.merge is None:
-                self._fold_lane = jax.jit(self._fold_lane_impl)
+                self._fold_lane = donated(self._fold_lane_impl)
         # batched lane-init: reset a GROUP of lanes to fresh state in one
         # dispatch (close's group reset, the storm-admission lane-init).
         # Duplicate indices are legal -- the same fresh value lands twice
         # -- so callers pad idx up to a lane bucket by repeating a lane.
-        self._reset_lanes = jax.jit(
-            lambda states, idx: jax.tree.map(
-                lambda x, f: x.at[idx].set(f), states, self._fresh))
+        self._reset_lanes = donated(
+            lambda states, idx: core_executor.reset_lanes(states, idx,
+                                                          self._fresh))
+        self._table_updates = 0    # lane-table update dispatches, lifetime
 
         # --- the lane buckets: 1, 2, 4, ... lanes per device, capped at
         # lanes_per_device (padding never outgrows the lane table); with
@@ -552,7 +566,7 @@ class SessionEngine:
                     "first-append entries (pass one per tenant, or None)")
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
-        clock = _ScanClock()
+        clock = _ScanClock(self._table_updates)
         with self.obs.span("engine.admit_storm", cat="admit",
                            n_tenants=len(tenants)) as sp:
             sids: List[int] = []
@@ -582,7 +596,7 @@ class SessionEngine:
                                   "n_queued_batch": len(sids) - len(admitted),
                                   "n_scan_dispatches": clock.segments,
                                   "admit_ms": round(ms, 3),
-                                  **clock.columns()})
+                                  **clock.columns(self._table_updates)})
         self._flush_no += 1
         return sids
 
@@ -698,7 +712,7 @@ class SessionEngine:
         """
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
-        clock = _ScanClock()
+        clock = _ScanClock(self._table_updates)
         with self.obs.span("engine.flush", scope="engine") as sp:
             force = set(force)
             self._admit()
@@ -732,7 +746,7 @@ class SessionEngine:
             sp.set(tuples=flushed_tuples, width=width)
         self._record_flush(flushed_tuples, lane_chunks, width, snap=snap,
                            ms=(time.perf_counter() - t0) * 1e3,
-                           extra={**clock.columns(),
+                           extra={**clock.columns(self._table_updates),
                                   "forced_sessions": len(force)})
         self._flush_no += 1
 
@@ -753,7 +767,7 @@ class SessionEngine:
         unchanged and the scan hits a pre-compiled bucket."""
         snap = compilemon.snapshot()
         t0 = time.perf_counter()
-        clock = _ScanClock()
+        clock = _ScanClock(self._table_updates)
         s = self._session(sid)
         if s.slot is None:
             raise QueuedSessionError(
@@ -772,7 +786,7 @@ class SessionEngine:
             sp.set(tuples=n_real, width=width)
         self._record_flush(n_real, group_chunks, width, scope="session",
                            snap=snap, ms=(time.perf_counter() - t0) * 1e3,
-                           extra=clock.columns())
+                           extra=clock.columns(self._table_updates))
         self._flush_no += 1
 
     def _flush_admission(self, sids: List[int], clock: "_ScanClock"):
@@ -874,8 +888,8 @@ class SessionEngine:
         t0 = time.perf_counter_ns()
         with self.obs.span("scan.scatter", cat="scan", scope=scope,
                            lanes=len(idx)):
-            self._states = jax.block_until_ready(
-                self._scatter(self._states, idx, sub))
+            self._update_table(self._scatter, idx, sub)
+            jax.block_until_ready(self._states)
         clock.lanes(gather_ns + time.perf_counter_ns() - t0, len(idx),
                     len(lanes))
         return width
@@ -890,10 +904,17 @@ class SessionEngine:
             part = lanes[lo:lo + cap]
             part = part + [part[0]] * (self._lane_bucket(len(part))
                                        - len(part))
-            states = self._reset_lanes(self._states,
-                                       np.asarray(part, np.int32))
-            self._states = (states if self._sharded is None
-                            else self._sharded.shard_states(states))
+            self._update_table(self._reset_lanes, np.asarray(part, np.int32))
+            if self._sharded is not None:
+                self._states = self._sharded.shard_states(self._states)
+
+    def _update_table(self, program, *args) -> None:
+        """Run a lane-table update ``program(states, *args)`` (the
+        scatter, fold or reset) and rebind ``self._states`` to its
+        result.  The program takes the table donated, so the call
+        deletes the old table: nothing may hold it across an update."""
+        self._states = program(self._states, *args)
+        self._table_updates += 1
 
     def _scan_batch(self, state, lane_chunks, lane_masks, row_sessions,
                     bucket: int, scope: str, clock: "_ScanClock"):
@@ -972,7 +993,10 @@ class SessionEngine:
         scratch states; then primes the remaining fixed-shape entry
         points (merge, fold, the secondary scheduler) -- so ``flush`` /
         ``flush_session`` / ``open_batch`` / ``query`` / ``close`` are
-        all compile-free afterwards.
+        all compile-free afterwards.  ``table_in_place`` ("n of m")
+        counts the primed lane-table update programs (each bucket's
+        scatter and reset, the fold) whose executable aliases the
+        donated table into its output.
 
         Needs the engine tuple dtype+shape: either call after the first
         ``append`` (``append`` triggers warmup automatically then), or
@@ -1007,6 +1031,7 @@ class SessionEngine:
         target = (None if self._sharded is None
                   else self._sharded.lane_sharding)
         n_dev = self.num_lanes // self.lanes_per_device
+        in_place: List[bool] = []       # per lane-table update program
         for b in self._lane_buckets:
             idx = jax.device_put(np.tile(np.arange(b, dtype=np.int32), n_dev),
                                  target)
@@ -1017,15 +1042,23 @@ class SessionEngine:
                 zm = jax.ShapeDtypeStruct((n_dev * b, w, c), np.bool_,
                                           sharding=target)
                 self._aot[(b, w)] = self._run.lower(sub, zc, zm).compile()
-            self._scatter(scratch, idx, sub)
-            reset = self._reset_lanes(scratch, np.arange(b, dtype=np.int32))
+            # the update programs take ``scratch`` donated: rebind it
+            scratch = self._scatter(scratch, idx, sub)
+            in_place.append(self._aliases_table(self._scatter, scratch,
+                                                idx, sub))
+            part = np.arange(b, dtype=np.int32)
+            scratch = self._reset_lanes(scratch, part)
+            in_place.append(self._aliases_table(self._reset_lanes, scratch,
+                                                part))
             if self._sharded is not None:
-                self._sharded.shard_states(reset)
+                scratch = self._sharded.shard_states(scratch)
         # remaining fixed-shape entry points (query/close/re-grant): a
         # plain execution populates their jit caches
         self._merge_lane(scratch, 0)
         if self.secondary_slots and self.spec.merge is None:
-            self._fold_lane(scratch, self.primary_slots, 0)
+            scratch = self._fold_lane(scratch, self.primary_slots, 0)
+            in_place.append(self._aliases_table(
+                self._fold_lane, scratch, self.primary_slots, 0))
         self._res.merge_state(self._fresh)
         self.plan_secondary(np.zeros(self.primary_slots, np.float32))
         d = compilemon.since(before)
@@ -1036,8 +1069,23 @@ class SessionEngine:
             "warmup_ms": round((time.perf_counter() - t0) * 1e3, 3),
             "warmup_compiles": int(d.n_compiles),
             "warmup_compile_ms": float(d.stall_ms),
+            "table_in_place": f"{sum(in_place)} of {len(in_place)}",
         }
         return self._aot_info
+
+    @staticmethod
+    def _aliases_table(program, states, *args) -> bool:
+        """Whether the compiled ``program(states, *args)`` updates the
+        lane table in place: its executable's ``input_output_alias``
+        maps every leaf of ``states`` (the first parameters) into the
+        output.  The executable is the one the program's last call
+        compiled; lowering it again compiles nothing."""
+        head = program.lower(states, *args).compile().as_text()
+        head = head.split("\n", 1)[0]
+        aliased = head.partition("input_output_alias=")[2]
+        aliased = aliased.partition("entry_computation_layout")[0]
+        params = {int(p) for p in re.findall(r"\}:\s*\((\d+),", aliased)}
+        return params >= set(range(len(jax.tree.leaves(states))))
 
     def _secondary_groups(self) -> Dict[int, List[int]]:
         """Primary slot -> the secondary lanes granted to it, for every
@@ -1229,8 +1277,8 @@ class SessionEngine:
             if old >= 0:
                 # the lifted §IV-B merge: shadow lane folds into its old
                 # session's primary lane before re-assignment
-                self._states = self._fold_lane(
-                    self._states, self.primary_slots + j, old)
+                self._update_table(self._fold_lane, self.primary_slots + j,
+                                   old)
                 self._slot_reschedules += 1
             self._sec_assign[j] = new[j]
             if self.obs.enabled and int(new[j]) >= 0:
