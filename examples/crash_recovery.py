@@ -22,9 +22,9 @@ process death with un-checkpointed WAL tail on disk.  The parent then
 
 Multi-device: with ``--chips N`` (N in {2,4,8}; on CPU under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``, CI uses 4) both
-processes run the engine with the slot lanes sharded over a ``lanes``
-mesh axis of N devices, so the recovery restores through the
-``executor.put_lanes`` + lane-sharding path.  The parent touches JAX
+processes run the engine with the slot lanes split over a ``lanes``
+mesh axis of N devices, so the recovery restores each device's share
+through the lane table's scatter.  The parent touches JAX
 only after the child is gone: one process at a time holds a chip.
 """
 import argparse
@@ -90,9 +90,8 @@ def main(workdir, chips: int):
 
     from repro.apps import histo
     spec, eng = make_engine(workdir, recovering=True, chips=chips)
-    if eng._sharded is not None:
-        print(f"recovering across {eng.num_lanes // eng.lanes_per_device} "
-              f"devices x {eng.lanes_per_device} lanes")
+    print(f"recovering across {eng.num_lanes // eng.lanes_per_device} "
+          f"device(s) x {eng.lanes_per_device} lanes")
     appended = {t: [batch(r, t) for r in range(PRE_ROUNDS + 1)]
                 for t in range(TENANTS)}
     total = sum(len(b) for bs in appended.values() for b in bs)

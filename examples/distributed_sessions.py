@@ -2,8 +2,8 @@
 
 One `serve.SessionEngine(mesh=...)` serves MORE tenants than a single
 device's lane budget: the lanes axis is split over the mesh
-(`core.distributed.make_lane_sharded_executor`, DESIGN.md §9), every
-device advances its local lanes in one shard_map'd vmapped scan, and a
+(`core.distributed.make_lane_table`, DESIGN.md §9), every device
+advances its local lanes in one shard_map'd vmapped scan, and a
 secondary-lane re-grant whose old owner lives on a different device runs
 the paper's §IV-B shadow-buffer merge as a psum collective.
 
@@ -11,7 +11,8 @@ The script drives Zipf-1.5 tenants with ragged appends (one
 deliberately hot so grants actually move), interleaves engine-wide
 flushes with per-session-flush queries, and asserts every answer
 bit-exact against BOTH the numpy oracle and an identically-driven
-single-device engine -- then prints the telemetry headlines.
+single-device engine (the same lane programs on a one-device mesh) --
+then prints the telemetry headlines.
 
     PYTHONPATH=src python examples/distributed_sessions.py
 """

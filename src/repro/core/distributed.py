@@ -31,13 +31,14 @@ near-uniform capacity -- measured by tests/test_distributed.py and
 examples/distributed_ditto.py.
 
 This module also hosts the SERVING-layer lift of the same mapping
-(DESIGN.md §9): ``make_lane_sharded_executor`` shards the slot *lanes*
-of ``serve.SessionEngine`` -- each lane a full resumable executor carry
--- along a mesh ``lanes`` axis, so one engine serves
-``P x lanes_per_device`` tenants.  The §IV-B shadow-buffer merge of a
-re-granted lane becomes a ``psum`` collective over the lanes axis (the
-re-granted lane and its old owner's primary lane may live on different
-devices).  Full mapping table + worked example: docs/distributed.md.
+(DESIGN.md §9): ``make_lane_table`` splits the slot *lanes* of
+``serve.SessionEngine`` -- each lane a full resumable executor carry --
+over a mesh ``lanes`` axis, so one engine serves ``P x
+lanes_per_device`` tenants; one device is the mesh of one.  The §IV-B
+shadow-buffer merge of a re-granted lane becomes a ``psum`` collective
+over the lanes axis (the re-granted lane and its old owner's primary
+lane may live on different devices).  Full mapping table + worked
+example: docs/distributed.md.
 """
 from __future__ import annotations
 
@@ -46,6 +47,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -57,9 +59,10 @@ from repro.core.types import DittoSpec, RoutePlan
 
 def lane_mesh(chips: int):
     """The ``lanes`` mesh over the first ``chips`` devices, for
-    ``serve.SessionEngine(mesh=...)``; ``None`` (the unsharded engine)
-    for one chip.  Entry points take the chip count as an argument: it
-    is never inferred from how many devices happen to be visible."""
+    ``serve.SessionEngine(mesh=...)``; ``None`` for one chip, which the
+    engine takes as the one-device mesh over the default device.  Entry
+    points take the chip count as an argument: it is never inferred
+    from how many devices happen to be visible."""
     devices = jax.devices()
     if not 1 <= chips <= len(devices):
         raise ValueError(f"chips={chips}: JAX sees {len(devices)} device(s)")
@@ -69,8 +72,8 @@ def lane_mesh(chips: int):
 
 
 def _auto_axes(mesh):
-    """``mesh`` with every axis of type Auto.  Both lifts index sharded
-    arrays with plain gathers and scatters (``executor.take_lanes`` /
+    """``mesh`` with every axis of type Auto.  Both lifts index arrays
+    with plain gathers and scatters (``executor.take_lanes`` /
     ``put_lanes``, the merger loop of ``run_stream``), which needs the
     compiler to propagate shardings; ``jax.make_mesh`` makes Explicit
     axes, under which such a gather is refused."""
@@ -193,189 +196,170 @@ def run_stream(spec: DittoSpec, mesh, tuples, num_pri: int, num_sec: int,
 
 
 # ---------------------------------------------------------------------------
-# Lane-sharded serving executor (DESIGN.md §9): slot lanes across devices
+# The serving engine's lane table (DESIGN.md §9): slot lanes across devices
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
-class ShardedLaneExecutor:
-    """A lanes-stacked ``ResumableExecutor`` sharded across a mesh axis.
+class LaneTable:
+    """The lane table of ``serve.SessionEngine``: ``num_lanes`` slot
+    lanes, each a whole ``ExecState`` of ``res``, stacked on a leading
+    axis that is split over the mesh's ``lanes`` axis -- one device
+    holding the whole table, or P devices x ``lanes_per_device`` lanes.
 
     Where ``make_distributed_executor`` maps one *PE* to one shard (the
-    routed dataflow inside a single stream), this maps one *slot lane*
-    -- a whole per-session executor carry -- to a mesh-shard slice, the
-    serving-layer lift: P devices x lanes_per_device lanes, each lane an
-    independent ``ExecState`` advanced by the vmapped chunk scan of its
-    local shard.  No collective is needed on the flush path (lanes are
-    independent streams); the collectives live in the slot re-scheduling
-    path, where the §IV-B shadow-buffer merge crosses devices:
+    routed dataflow inside a single stream), this maps one *slot lane* to
+    a shard slice.  Each operation is one body over a device's slice,
+    lifted by ``shard_map``; the same programs run on one device and on
+    many.  Lane ids are per device for the bucket operations and global
+    for the one-lane ones:
 
-      run_lanes(states, chunks, mask)  one shard_map'd step: every shard
-                                       vmaps the chunk scan over its
-                                       local lanes (zero communication)
-      take_local(states, idx)          the lane bucket of a flush: every
-                                       shard gathers its own local lanes
-                                       ``idx`` (an equal count per
-                                       shard; no lane crosses devices)
-      put_local(states, idx, sub)      its inverse: every shard writes
-                                       its slice of ``sub`` back into
-                                       its local lanes ``idx``
-      fold_lane(states, src, dst)      merge-before-reassign as a
-                                       collective: src's merged buffers
-                                       are masked out locally, psum'd
-                                       over the lanes axis, combined
-                                       (add/max) into dst's primary
-                                       region on dst's shard, and src is
-                                       reset to fresh on its shard
-      merge_lane(states, i)            replicated merged snapshot of one
-                                       lane (the query path), same
-                                       mask + psum selection
-      reset_lane(states, i)            fresh-lane reset on i's shard
+      gather(states, idx)        the lane bucket of a flush: each device
+                                 takes its own local lanes ``idx`` (an
+                                 equal count per device; no lane crosses
+                                 devices) -- ``executor.take_lanes``
+      scatter(states, idx, sub)  its inverse, the table donated --
+                                 ``executor.put_lanes``
+      scan(sub, chunks, mask)    each device vmaps the chunk scan over its
+                                 bucket rows (no communication) --
+                                 ``ResumableExecutor.scan_lanes``
+      snapshot(states, i)        replicated merged snapshot of global lane
+                                 ``i`` (the query path): its owner reads
+                                 the one lane, the others give zeros, and
+                                 a psum over the axis combines them
+      fold(states, src, dst)     §IV-B merge-before-reassign, the table
+                                 donated: src's snapshot (as above) is
+                                 combined (add/max) into dst's primary
+                                 region on dst's device and src is reset
+                                 on its own; ``None`` for apps whose
+                                 buffers do not combine (``spec.merge``)
+      reset(states, idx)         lanes ``idx`` (local, ``-1`` pads last)
+                                 to fresh state, the table donated --
+                                 ``executor.reset_lanes``
 
-    ``num_lanes`` must divide evenly over the mesh axis (shard_map's
-    even-split contract); ``serve.SessionEngine`` surfaces the
-    divisibility requirement at construction.  A mesh of size 1 degenerates to the
-    single-device engine bit-exactly: the vmap body is identical and the
-    psum/selection collectives are identities over a 1-sized axis.
+    The donated programs write the touched lanes in place: the caller
+    must rebind its table to their result.  ``num_lanes`` must divide
+    evenly over the mesh axis (shard_map's even-split contract).
     """
 
     res: core_executor.ResumableExecutor
     mesh: object
     num_lanes: int
-    axis: str
     lanes_per_device: int
-    lane_sharding: NamedSharding
-    run_lanes: Callable = dataclasses.field(repr=False)
-    take_local: Callable = dataclasses.field(repr=False)
-    put_local: Callable = dataclasses.field(repr=False)
-    fold_lane: Optional[Callable] = dataclasses.field(repr=False)
-    merge_lane: Callable = dataclasses.field(repr=False)
-    reset_lane: Callable = dataclasses.field(repr=False)
+    sharding: NamedSharding
+    gather: Callable = dataclasses.field(repr=False)
+    scatter: Callable = dataclasses.field(repr=False)
+    scan: Callable = dataclasses.field(repr=False)
+    snapshot: Callable = dataclasses.field(repr=False)
+    fold: Optional[Callable] = dataclasses.field(repr=False)
+    reset: Callable = dataclasses.field(repr=False)
+
+    @property
+    def num_devices(self) -> int:
+        return self.num_lanes // self.lanes_per_device
 
     def init_states(self):
-        """Fresh lanes-stacked ``ExecState``, device_put to the lane
-        sharding (leaf axis 0 split over the mesh's lanes axis)."""
+        """Fresh lanes-stacked ``ExecState`` on the lane sharding."""
         stacked = core_executor.stack_states(self.res.init_state(),
                                              self.num_lanes)
-        return jax.device_put(stacked, self.lane_sharding)
-
-    def shard_states(self, states):
-        """Re-pin a lanes-stacked state to the lane sharding (after a
-        host-side or cross-shard edit, e.g. ``executor.put_lanes``)."""
-        return jax.device_put(states, self.lane_sharding)
+        return jax.device_put(stacked, self.sharding)
 
 
-def make_lane_sharded_executor(res: core_executor.ResumableExecutor, mesh,
-                               num_lanes: int, *,
-                               axis: str = "lanes") -> ShardedLaneExecutor:
-    """Build the shard_map'd lane operations for ``num_lanes`` slot lanes
-    of ``res`` split over ``mesh``'s ``axis``.  See ShardedLaneExecutor."""
-    num_dev = dict(mesh.shape)[axis]
+def make_lane_table(res: core_executor.ResumableExecutor, mesh,
+                    num_lanes: int) -> LaneTable:
+    """Build the lane operations for ``num_lanes`` slot lanes of ``res``
+    split over ``mesh``'s ``lanes`` axis; ``mesh=None`` is a one-device
+    mesh over the default device.  See LaneTable."""
+    if mesh is None:
+        mesh = Mesh(np.array(jax.devices()[:1]), ("lanes",))
+    if "lanes" not in dict(mesh.shape):
+        raise ValueError(f"mesh has no 'lanes' axis; mesh axes: "
+                         f"{tuple(dict(mesh.shape))}")
+    num_dev = dict(mesh.shape)["lanes"]
     if num_lanes % num_dev:
         raise ValueError(
             f"num_lanes={num_lanes} must be divisible by the mesh's "
-            f"'{axis}' axis size {num_dev} (shard_map splits the lanes "
+            f"'lanes' axis size {num_dev} (shard_map splits the lanes "
             "axis evenly); pad primary/secondary slots up")
-    lanes_per_device = num_lanes // num_dev
+    lpd = num_lanes // num_dev
     mesh = _auto_axes(mesh)
-    pspec = P(axis)
-    sharding = NamedSharding(mesh, pspec)
+    lanes, one = P("lanes"), P()
     fresh = res.init_state()
 
-    def local_ids():
-        """Global lane ids of this shard's local slice."""
-        return (jax.lax.axis_index(axis) * lanes_per_device
-                + jnp.arange(lanes_per_device, dtype=jnp.int32))
+    def lift(body, in_specs, out_specs, donate=False):
+        # explicit output shardings: on one device the compiler may hand
+        # a leaf back replicated, and a table that changed sharding
+        # would miss the jit caches that ``warmup()`` filled
+        out = jax.tree.map(lambda spec: NamedSharding(mesh, spec),
+                           out_specs, is_leaf=lambda x: isinstance(x, P))
+        return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs),
+                       out_shardings=out,
+                       donate_argnums=0 if donate else ())
 
-    def select(tree, sel):
-        """Zero out every local lane but ``sel``'s, then drop the lane
-        axis by summation: at most one local lane matches, so this
-        extracts it exactly (adding zeros is exact for int and float
-        alike); shards owning no match produce an all-zero pytree."""
-        def leaf(x):
-            selb = sel.reshape(sel.shape + (1,) * (x.ndim - 1))
-            return jnp.where(selb, x, jnp.zeros((), x.dtype)).sum(axis=0)
-        return jax.tree.map(leaf, tree)
+    def local(i):
+        """Local index of global lane ``i`` on this device (0 where the
+        device does not hold it) and whether it holds it."""
+        base = jax.lax.axis_index("lanes") * lpd
+        own = (i >= base) & (i < base + lpd)
+        return jnp.where(own, i - base, 0), own
 
-    def merge_selected(states, sel):
-        """Merged snapshot of the ONE globally selected lane, computed
-        with a single per-shard merge: select the lane's ExecState
-        locally, merge it once, zero the result on non-owner shards
-        (whose selected state is all-zero garbage), and let the caller
-        psum.  Exact for any dtype -- only the owner contributes."""
-        merged = res.merge_state_raw(select(states, sel))
-        own = sel.any()
+    def lane(x, k):
+        return jax.lax.dynamic_index_in_dim(x, k, 0, keepdims=False)
+
+    def take(states, i):
+        """Global lane ``i``'s state, read by dynamic index (a lane of
+        this device where it does not own ``i``), its local index and
+        whether this device owns it."""
+        k, own = local(i)
+        return jax.tree.map(lambda x: lane(x, k), states), k, own
+
+    def merged(one_lane, own):
+        """The merged snapshot of ``one_lane`` from its owner, zeros
+        from every other device, summed over the axis: exact for any
+        dtype, since only the owner contributes."""
         return jax.tree.map(
-            lambda x: jnp.where(own, x, jnp.zeros((), x.dtype)), merged)
+            lambda x: jax.lax.psum(jnp.where(own, x, jnp.zeros((), x.dtype)),
+                                   "lanes"),
+            res.merge_state_raw(one_lane))
 
-    def set_lane(states, sel, value):
-        """Overwrite the local lanes matching ``sel`` with ``value`` (a
-        single-lane pytree, broadcast over the selector)."""
-        def leaf(x, v):
-            selb = sel.reshape(sel.shape + (1,) * (x.ndim - 1))
-            return jnp.where(selb, v, x)
-        return jax.tree.map(leaf, states, value)
+    def snapshot(states, i):
+        one_lane, _, own = take(states, i)
+        return merged(one_lane, own)
 
-    def _run(states, chunks, mask):
-        return jax.vmap(res.scan_chunks)(states, chunks, mask)
+    def reset(states, idx):
+        return core_executor.reset_lanes(states, idx, fresh)
 
-    run_lanes = jax.jit(jax.shard_map(
-        _run, mesh=mesh, in_specs=(pspec, pspec, pspec),
-        out_specs=(pspec, pspec)))
-    take_local = jax.jit(jax.shard_map(
-        core_executor.take_lanes, mesh=mesh, in_specs=(pspec, pspec),
-        out_specs=pspec))
-    put_local = jax.jit(jax.shard_map(
-        core_executor.put_lanes, mesh=mesh, in_specs=(pspec, pspec, pspec),
-        out_specs=pspec))
-
-    def _merge(states, i):
-        picked = merge_selected(states, local_ids() == i)
-        return jax.tree.map(lambda x: jax.lax.psum(x, axis), picked)
-
-    merge_lane = jax.jit(jax.shard_map(
-        _merge, mesh=mesh, in_specs=(pspec, P()), out_specs=P()))
-
-    def _reset(states, i):
-        return set_lane(states, local_ids() == i, fresh)
-
-    reset_lane = jax.jit(jax.shard_map(
-        _reset, mesh=mesh, in_specs=(pspec, P()), out_specs=pspec))
-
-    fold_lane = None
+    fold = None
     if res.spec.merge is None:        # decomposable buffers only (add/max)
-        def local(i):
-            """Local index of global lane ``i`` on this shard (0 where
-            the shard does not hold it) and whether it holds it."""
-            base = jax.lax.axis_index(axis) * lanes_per_device
-            own = (i >= base) & (i < base + lanes_per_device)
-            return jnp.where(own, i - base, 0), own
-
         def _fold(states, src, dst):
-            # src's merged contribution, delivered to every shard: the
-            # §IV-B merge-before-reassign expressed as a collective
-            contrib = jax.lax.psum(
-                merge_selected(states, local_ids() == src), axis)
+            src_lane, s, own_s = take(states, src)
+            contrib = merged(src_lane, own_s)
             # each write touches one lane (unchanged off its owner), so
-            # XLA updates the table in place when the caller donates it
+            # XLA updates the donated table in place
             d, own = local(dst)
             bufs = states.buffers                    # [L, M+X, *local]
-            m = res.num_pri
-            row = bufs[d, :m]
+            row = bufs[d, :res.num_pri]
             comb = (row + contrib if res.spec.combine == "add"
                     else jnp.maximum(row, contrib))
-            bufs = bufs.at[d, :m].set(jnp.where(own, comb, row))
+            bufs = bufs.at[d, :res.num_pri].set(jnp.where(own, comb, row))
             states = dataclasses.replace(states, buffers=bufs)
-            s, own = local(src)
+            # src's lane as it stands now: where this device does not
+            # own src, its local index 0 may be dst's, just updated
+            now = dataclasses.replace(src_lane, buffers=lane(bufs, s))
             return jax.tree.map(
-                lambda x, f: x.at[s].set(jnp.where(own, f, x[s])),
-                states, fresh)
+                lambda x, f, v: jax.lax.dynamic_update_index_in_dim(
+                    x, jnp.where(own_s, f, v), s, 0),
+                states, fresh, now)
 
-        fold_lane = jax.jit(jax.shard_map(
-            _fold, mesh=mesh, in_specs=(pspec, P(), P()), out_specs=pspec))
+        fold = lift(_fold, (lanes, one, one), lanes, donate=True)
 
-    return ShardedLaneExecutor(
-        res=res, mesh=mesh, num_lanes=num_lanes, axis=axis,
-        lanes_per_device=lanes_per_device, lane_sharding=sharding,
-        run_lanes=run_lanes, take_local=take_local, put_local=put_local,
-        fold_lane=fold_lane, merge_lane=merge_lane,
-        reset_lane=reset_lane)
+    return LaneTable(
+        res=res, mesh=mesh, num_lanes=num_lanes, lanes_per_device=lpd,
+        sharding=NamedSharding(mesh, lanes),
+        gather=lift(core_executor.take_lanes, (lanes, lanes), lanes),
+        scatter=lift(core_executor.put_lanes, (lanes, lanes, lanes), lanes,
+                     donate=True),
+        scan=lift(res.scan_lanes, (lanes, lanes, lanes), (lanes, lanes)),
+        snapshot=lift(snapshot, (lanes, one), one),
+        fold=fold,
+        reset=lift(reset, (lanes, lanes), lanes, donate=True))

@@ -319,7 +319,7 @@ class ResumableExecutor:
     mask)) -> (state, stats)`` for callers that compose their own scans
     or vmaps (e.g. the slot-stacked SessionEngine);
     ``merge_state_raw`` is the un-jitted snapshot for the same purpose
-    (vmapped per lane under ``shard_map`` in the distributed engine).
+    (the lane table's snapshot and fold, under ``shard_map``).
     """
 
     spec: DittoSpec
@@ -344,7 +344,8 @@ class ResumableExecutor:
         ``chunks[lane, k]`` per lane in one batched scan.
 
         This is the **lowerable entry point** of the serving layer's hot
-        path: ``serve.SessionEngine`` wraps it in ``jax.jit`` and, with
+        path: ``core.distributed.make_lane_table`` lifts it over the
+        mesh's lanes axis and ``serve.SessionEngine``, with
         ``aot_buckets=`` enabled, AOT-lowers and compiles one executable
         per (lane count, scan width) shape bucket at warmup
         (``jit(scan_lanes).lower(...).compile()``), so ragged traffic
@@ -422,22 +423,18 @@ def make_resumable_executor(
 def stack_states(state: ExecState, num_lanes: int) -> ExecState:
     """Broadcast one ``ExecState`` into a lanes-stacked pytree: every leaf
     gains a leading ``[num_lanes]`` axis.  This is the slot-lane state of
-    ``serve.SessionEngine``; shard axis 0 over a mesh's ``lanes`` axis
-    (``core.distributed.make_lane_sharded_executor``) for the distributed
-    engine (DESIGN.md §9)."""
+    ``serve.SessionEngine``, whose axis 0 is split over a mesh's ``lanes``
+    axis (``core.distributed.make_lane_table``, DESIGN.md §9)."""
     return jax.tree.map(lambda x: jnp.stack([x] * num_lanes), state)
 
 
 def take_lanes(states: ExecState, idx) -> ExecState:
     """Gather lane sub-states ``idx`` (int array) out of a lanes-stacked
-    ``ExecState``.  On a sharded state this is the cross-device resume
-    path: the gathered lanes materialize wherever the caller computes,
-    regardless of which shard held them -- an ``ExecState`` is an
-    ordinary pytree of arrays, so suspending on one device and resuming
-    on another is just this gather + ``put_lanes`` scatter.  The same
-    pair is the durability snapshot unit (DESIGN.md §10): gathering all
-    lanes yields the host-serializable engine state a checkpoint
-    persists, and recovery scatters it back with ``put_lanes``."""
+    ``ExecState`` -- an ``ExecState`` is an ordinary pytree of arrays,
+    so suspending a lane and resuming it later is this gather plus the
+    ``put_lanes`` scatter.  ``core.distributed.make_lane_table`` runs
+    both on each device's slice of the serving engine's lane table
+    (DESIGN.md §9)."""
     return jax.tree.map(lambda x: x[idx], states)
 
 
@@ -450,21 +447,24 @@ def put_lanes(states: ExecState, idx, sub: ExecState) -> ExecState:
 
 def reset_lanes(states: ExecState, idx, fresh: ExecState) -> ExecState:
     """Overwrite lanes ``idx`` of a lanes-stacked ``ExecState`` with the
-    one-lane state ``fresh`` (an id may repeat)."""
-    return _write_lanes(states, idx, lambda k: fresh)
+    one-lane state ``fresh`` (an id may repeat; ids ``-1`` are pads,
+    placed after every real id, and write nothing)."""
+    return _write_lanes(states, idx, lambda k: fresh,
+                        count=jnp.sum(idx >= 0))
 
 
-def _write_lanes(states: ExecState, idx, lane) -> ExecState:
+def _write_lanes(states: ExecState, idx, lane, count=None) -> ExecState:
     """Write ``lane(k)`` (a one-lane state) into lane ``idx[k]`` for each
-    ``k``, one dynamic update per lane.  A table the caller donates is
-    then written in place whatever its device layout: a multi-lane
-    scatter makes the TPU lay the whole table out lane-major and back
-    (two copies of it) around the write."""
+    ``k < count`` (default: every ``k``), one dynamic update per lane.
+    A table the caller donates is then written in place whatever its
+    device layout: a multi-lane scatter makes the TPU lay the whole
+    table out lane-major and back (two copies of it) around the write."""
     def write(k, st):
         return jax.tree.map(
             lambda x, v: jax.lax.dynamic_update_index_in_dim(
                 x, v.astype(x.dtype), idx[k], 0), st, lane(k))
-    return jax.lax.fori_loop(0, len(idx), write, states)
+    return jax.lax.fori_loop(0, len(idx) if count is None else count,
+                             write, states)
 
 
 def make_multistream_executor(

@@ -31,12 +31,11 @@ the newest readable checkpoint, then replay ONLY the WAL tail past its
 watermark.  Replayed appends land in session backlogs exactly as the
 original calls did, and the engine's chunking-invariance guarantee (any
 partition of a stream into appends/flushes merges to identical buffers)
-makes every subsequent ``query()`` bit-exact vs an uninterrupted run --
-in local mode and in ``mesh=`` lane-sharded mode alike (the restored
-lanes are scattered back with ``executor.put_lanes`` and re-pinned to the
-lane sharding).  A checkpoint is mesh-agnostic: a state saved by a local
-engine restores onto a meshed one and vice versa (the elastic property of
-``checkpoint.CheckpointManager``, inherited).
+makes every subsequent ``query()`` bit-exact vs an uninterrupted run, on
+any mesh (the restored lanes go back through the lane table's scatter).
+A checkpoint is mesh-agnostic: the table is saved in global lane order,
+so a state saved on one mesh restores onto another (the elastic property
+of ``checkpoint.CheckpointManager``, inherited).
 
 Failure model
   The engine process can die at ANY instruction (SIGKILL, OOM, node
@@ -73,7 +72,6 @@ from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.ckpt import CheckpointManager
@@ -299,7 +297,7 @@ _CONFIG_NAME = "config.json"
 _TELEMETRY_KEEP = 256    # per-flush telemetry rows carried per checkpoint
 # SessionEngine kwargs that round-trip through config.json (JSON scalars
 # only; spec and mesh are live objects the recover() caller supplies).
-_CFG_ENGINE_KW = ("kernel_backend", "lanes_axis", "profile_chunks",
+_CFG_ENGINE_KW = ("kernel_backend", "profile_chunks",
                   "threshold", "mem_width_tuples", "static_plan",
                   "aot_buckets", "telemetry_cap")
 
@@ -453,8 +451,8 @@ class DurableSessionEngine(SessionEngine):
     # ------------------------------------------------------------ checkpoint
     def checkpoint(self, block: bool = False) -> int:
         """Persist a consistent cut of the engine: the lanes-stacked
-        ``ExecState`` (gathered with ``executor.take_lanes`` -- across
-        shards in ``mesh=`` mode) plus scheduler/session metadata and the
+        ``ExecState`` (the whole lane table, copied to the host in global
+        lane order) plus scheduler/session metadata and the
         covering WAL seq (the flush watermark).  The snapshot is host-
         side before this returns; serialization runs async unless
         ``block``.  A blocking checkpoint also GCs WAL records every
@@ -463,9 +461,7 @@ class DurableSessionEngine(SessionEngine):
         with self.obs.span("ckpt.save", cat="ckpt",
                            block=bool(block)) as sp:
             upto = self._wal.seq - 1    # every record logged so far
-            idx = jnp.arange(self.num_lanes, dtype=jnp.int32)
-            lanes = jax.tree.map(np.asarray,
-                                 self._take_lanes(self._states, idx))
+            lanes = jax.tree.map(np.asarray, self._states)
             step = self._ckpt_step
             self._ckpt_step += 1
             meta = self._capture_meta(upto, step)
@@ -600,12 +596,13 @@ class DurableSessionEngine(SessionEngine):
                     self._restore_meta(meta)
                     wal_seq = int(meta["wal_seq"])
                     ck_step = int(meta["step"])
-                    idx = jnp.arange(self.num_lanes, dtype=jnp.int32)
-                    lanes = jax.tree.map(jnp.asarray, ck["lanes"])
-                    self._update_table(self._put_lanes, idx, lanes)
-                    if self._sharded is not None:
-                        self._states = self._sharded.shard_states(
-                            self._states)
+                    table = self._table
+                    idx = np.tile(np.arange(self.lanes_per_device,
+                                            dtype=np.int32),
+                                  table.num_devices)
+                    self._update_table(
+                        table.scatter, *jax.device_put(
+                            (idx, ck["lanes"]), table.sharding))
             if self._aot_widths and self._dtype is not None:
                 # land the restored engine in the same buckets BEFORE the
                 # WAL tail replays: replayed appends/flushes must not
@@ -699,8 +696,8 @@ def recover(spec, directory: os.PathLike, *, mesh=None, guard=None,
     """Resume a durable engine from ``directory``: rebuild it from
     ``config.json`` (``overrides`` win over saved knobs; ``spec`` must be
     the same application the directory was serving), restore the newest
-    readable checkpoint, scatter the lanes back (``executor.put_lanes``,
-    re-pinned to the lane sharding when ``mesh=`` is given), and replay
+    readable checkpoint, scatter the lanes back through the lane table
+    (on ``mesh=``, or the default device's), and replay
     the WAL tail past the watermark.  Every open session then answers
     ``query()`` bit-exactly as an uninterrupted run would."""
     directory = Path(directory)
@@ -714,7 +711,10 @@ def recover(spec, directory: os.PathLike, *, mesh=None, guard=None,
         primary_slots=cfg["primary_slots"],
         secondary_slots=cfg["secondary_slots"],
         min_grant_chunks=cfg["min_grant_chunks"],
-        **cfg.get("engine_kw", {}))
+        # older configs also carry "lanes_axis", whose one value the
+        # lane table now fixes
+        **{k: v for k, v in cfg.get("engine_kw", {}).items()
+           if k in _CFG_ENGINE_KW})
     ctl = {k: overrides.pop(k, cfg[k])
            for k in ("checkpoint_every", "keep", "wal_sync")}
     kw.update(overrides)

@@ -53,16 +53,19 @@ Lane buckets (active-lane flushes)
   flush costs what its active lanes cost, not what the lane table
   holds, and its host bookkeeping walks only the sessions with work.
 
-Distributed mode (DESIGN.md §9, docs/distributed.md)
-  ``SessionEngine(mesh=...)`` shards the lane axis over the mesh's
-  ``lanes`` axis via ``core.distributed.make_lane_sharded_executor``:
-  P devices x lanes_per_device lanes, one engine serving more tenants
-  than one device's lane budget.  Flushes stay collective-free: each
-  device gathers its own active lanes into the bucket of the busiest
-  device's count and the shard_map'd scan vmaps over them; a
-  cross-device slot re-grant runs the §IV-B shadow-buffer merge as a
-  psum over the lanes axis.  A mesh of size 1 is bit-exact vs the
-  unsharded engine.
+The lane table (DESIGN.md §9, docs/distributed.md)
+  The lanes live in one table, split over the ``lanes`` axis of a mesh
+  (``core.distributed.make_lane_table``): ``mesh=None`` is the mesh of
+  the default device, ``SessionEngine(mesh=...)`` spreads P devices x
+  lanes_per_device lanes, one engine serving more tenants than one
+  device's lane budget.  Every lane operation -- gather, scatter, scan,
+  snapshot, fold, reset -- is one body over a device's slice lifted by
+  ``shard_map``, so one chip and four run the same programs.  Flushes
+  stay collective-free: each device gathers its own active lanes into
+  the bucket of the busiest device's count and scans them; a snapshot
+  reads its one lane on the owning device, and a cross-device slot
+  re-grant runs the §IV-B shadow-buffer merge as a psum over the lanes
+  axis.
 
 AOT shape buckets (compile-stall elimination)
   Ragged appends produce ragged flush batches, and every new
@@ -71,9 +74,9 @@ AOT shape buckets (compile-stall elimination)
   ``SessionEngine(aot_buckets=W)`` scan widths round up to powers of
   two and are chopped into segments of at most ``W``, and ``warmup()``
   AOT-lowers and compiles ONE executable per (lane bucket, width) up
-  front (``jit(scan_lanes).lower().compile()`` on
-  ``core.executor.ResumableExecutor.scan_lanes``, local and mesh
-  variants alike) and primes every fixed-shape helper, so steady-state
+  front (the lane table's scan of
+  ``core.executor.ResumableExecutor.scan_lanes``, lowered and compiled)
+  and primes every fixed-shape helper, so steady-state
   traffic -- however ragged -- never compiles again.  Warmup runs
   explicitly or at the first ``append`` (when the tuple dtype/shape
   becomes known); ``recover`` lands a restored engine in the same
@@ -90,7 +93,7 @@ Batched admission (session storms)
   same AOT width segments as a flush: O(buckets) dispatches for a
   thousand-session storm.  The storm's scan uses the lane buckets of
   every flush, so the zero-steady-retrace invariant holds THROUGH
-  storms, local and mesh alike.  Ragged first-append
+  storms, on any mesh.  Ragged first-append
   tails stay buffered (answers are chunking-invariant), keeping the
   storm path bit-exact vs serial admission.  Overflow is strictly
   FIFO: tenants past ``primary_slots`` queue in ``open_batch`` call
@@ -127,11 +130,11 @@ Telemetry + observability (DESIGN.md §11, docs/observability.md)
 
 Durability (DESIGN.md §10, docs/durability.md)
   ``serve.durability`` wraps this engine in a per-tenant write-ahead
-  log plus periodic lane-state checkpoints (``executor.take_lanes`` of
-  every lane through ``checkpoint.CheckpointManager``);
+  log plus periodic lane-state checkpoints (the whole lane table
+  through ``checkpoint.CheckpointManager``);
   ``SessionEngine.recover`` restores the newest checkpoint, replays
   only the WAL tail past its watermark, and resumes every open session
-  bit-exactly after a crash -- in local and ``mesh=`` mode alike.
+  bit-exactly after a crash, on any mesh.
 """
 from __future__ import annotations
 
@@ -148,6 +151,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import compilemon
+from repro.core import distributed as core_distributed
 from repro.core import executor as core_executor
 from repro.core import scheduler
 from repro.data.pipeline import pad_tail_chunk
@@ -335,13 +339,12 @@ class SessionEngine:
       min_grant_chunks: a session must have at least this many backlog
         chunks before it can be granted a secondary lane (a helper lane
         for <2 chunks cannot shorten the scan).
-      mesh: a ``jax.sharding.Mesh`` with a ``lanes_axis`` axis.  When
-        given, the slot lanes are sharded over that axis (DESIGN.md §9):
+      mesh: a ``jax.sharding.Mesh`` with a ``lanes`` axis, over which
+        the slot lanes are split (DESIGN.md §9):
         ``primary_slots + secondary_slots`` must be divisible by the
-        axis size.  ``mesh=None`` (default) keeps everything on the
-        current device; a mesh of size 1 is bit-exact vs ``mesh=None``.
-      lanes_axis: the mesh axis name holding the lanes (default
-        ``"lanes"``).
+        axis size.  ``mesh=None`` (default) is the one-device mesh over
+        the default device; every mesh runs the same lane programs
+        (``core.distributed.make_lane_table``).
       obs: observability wiring (``repro.obs``): ``None`` -> a fresh
         enabled ``Observability`` bundle on ``self.obs``; ``False`` ->
         a disabled bundle (every metric op / span an early return); an
@@ -368,8 +371,7 @@ class SessionEngine:
                  num_sec: Optional[int] = None,
                  chunk_size: Optional[int] = None, tuned=None,
                  primary_slots: int = 4, secondary_slots: int = 2,
-                 min_grant_chunks: int = 2, mesh=None,
-                 lanes_axis: str = "lanes", aot_buckets=None,
+                 min_grant_chunks: int = 2, mesh=None, aot_buckets=None,
                  kernel_backend: Optional[str] = None, obs=None,
                  telemetry_cap: Optional[int] = 4096, **executor_kw):
         if tuned is not None:
@@ -386,16 +388,11 @@ class SessionEngine:
             raise ValueError(
                 f"{spec.name}: non-decomposable buffers cannot be combined "
                 "across lanes; use secondary_slots=0")
-        if mesh is not None and lanes_axis not in dict(mesh.shape):
-            raise ValueError(
-                f"mesh has no '{lanes_axis}' axis; mesh axes: "
-                f"{tuple(dict(mesh.shape))}")
         self.spec = spec
         self.primary_slots = primary_slots
         self.secondary_slots = secondary_slots
         self.min_grant_chunks = min_grant_chunks
         self.num_lanes = primary_slots + secondary_slots
-        self.mesh = mesh
 
         self._res = core_executor.make_resumable_executor(
             spec, num_pri, num_sec, chunk_size,
@@ -404,48 +401,17 @@ class SessionEngine:
         self.chunk_size = self._res.chunk_size
         fresh = self._res.init_state()
         self._fresh = fresh
-        self._sharded = None
-        # every flush path scans a BUCKET of lanes: the lanes that carry
-        # a chunk, gathered (per device under ``mesh=``) into a
-        # power-of-two lane count, scanned, scattered back.  The engine
-        # owns the lane table, so each program that returns an updated
-        # table (scatter, fold, reset) takes it DONATED: XLA writes the
-        # touched lanes in place instead of copying the whole table
-        # first, and the old table is deleted -- every caller rebinds
-        # ``self._states`` to the result (``_update_table``)
-        donated = functools.partial(jax.jit, donate_argnums=0)
-        self._take_lanes = jax.jit(core_executor.take_lanes)
-        self._put_lanes = donated(core_executor.put_lanes)
-        if mesh is not None:
-            from repro.core import distributed as core_distributed
-            self._sharded = core_distributed.make_lane_sharded_executor(
-                self._res, mesh, self.num_lanes, axis=lanes_axis)
-            self.lanes_per_device = self._sharded.lanes_per_device
-            self._states = self._sharded.init_states()
-            self._gather = self._sharded.take_local
-            self._scatter = donated(self._sharded.put_local)
-            self._run = self._sharded.run_lanes
-            self._merge_lane = self._sharded.merge_lane
-            if spec.merge is None:
-                self._fold_lane = donated(self._sharded.fold_lane)
-        else:
-            self.lanes_per_device = self.num_lanes
-            self._states = core_executor.stack_states(fresh, self.num_lanes)
-            self._gather = self._take_lanes
-            self._scatter = self._put_lanes
-            self._run = jax.jit(self._res.scan_lanes)
-            self._merge_lane = jax.jit(
-                lambda states, i: self._res.merge_state(
-                    jax.tree.map(lambda x: x[i], states)))
-            if spec.merge is None:
-                self._fold_lane = donated(self._fold_lane_impl)
-        # batched lane-init: reset a GROUP of lanes to fresh state in one
-        # dispatch (close's group reset, the storm-admission lane-init).
-        # Duplicate indices are legal -- the same fresh value lands twice
-        # -- so callers pad idx up to a lane bucket by repeating a lane.
-        self._reset_lanes = donated(
-            lambda states, idx: core_executor.reset_lanes(states, idx,
-                                                          self._fresh))
+        # the lane table: every flush path scans a BUCKET of lanes (the
+        # lanes that carry a chunk, gathered on each device into a
+        # power-of-two lane count), scanned, scattered back.  The engine
+        # owns the table, and the programs that return an updated table
+        # (scatter, fold, reset) take it DONATED: XLA writes the touched
+        # lanes in place, and the old table is deleted -- every caller
+        # rebinds ``self._states`` to the result (``_update_table``)
+        self._table = core_distributed.make_lane_table(
+            self._res, mesh, self.num_lanes)
+        self.lanes_per_device = self._table.lanes_per_device
+        self._states = self._table.init_states()
         self._table_updates = 0    # lane-table update dispatches, lifetime
 
         # --- the lane buckets: 1, 2, 4, ... lanes per device, capped at
@@ -835,18 +801,19 @@ class SessionEngine:
         padding lanes always exist."""
         return min(1 << (n - 1).bit_length(), self.lanes_per_device)
 
-    def _bucket_rows(self, lanes: List[int]):
+    def _bucket_rows(self, lanes: List[int], fill: bool = True):
         """Lay the distinct lane ids ``lanes`` out as a lane bucket.
 
         Each device takes its own lanes (none cross devices) and pads
-        them up to ``b``, the bucket of the busiest device's count,
-        with other lanes of the same device, which will carry all-masked
-        chunks.  Returns ``(rows, idx, b)``: ``rows[k]`` is the position
-        in ``lanes`` of the bucket's row ``k`` (``-1`` for a pad) and
-        ``idx[k]`` its lane id on its device; device ``d`` holds rows
-        ``d*b .. (d+1)*b - 1``.  O(bucket + lanes)."""
+        them up to ``b``, the bucket of the busiest device's count: with
+        other lanes of the same device, which will carry all-masked
+        chunks (``fill``), or with ``-1`` (the reset's pads).  Returns
+        ``(rows, idx, b)``: ``rows[k]`` is the position in ``lanes`` of
+        the bucket's row ``k`` (``-1`` for a pad) and ``idx[k]`` its lane
+        id on its device; device ``d`` holds rows ``d*b .. (d+1)*b - 1``.
+        O(bucket + lanes)."""
         lpd = self.lanes_per_device
-        per: List[List[int]] = [[] for _ in range(self.num_lanes // lpd)]
+        per: List[List[int]] = [[] for _ in range(self._table.num_devices)]
         for pos, ln in enumerate(lanes):
             per[ln // lpd].append(pos)
         b = self._lane_bucket(max(len(p) for p in per))
@@ -857,7 +824,8 @@ class SessionEngine:
             taken = set(local)
             pads = (i for i in range(lpd) if i not in taken)
             rows += ps + [-1] * (b - len(ps))
-            idx += local + [next(pads) for _ in range(b - len(ps))]
+            idx += local + [next(pads) if fill else -1
+                            for _ in range(b - len(ps))]
         return rows, np.asarray(idx, np.int32), b
 
     def _scan_lanes(self, lanes, lane_chunks, lane_masks, row_sessions,
@@ -865,22 +833,21 @@ class SessionEngine:
         """Advance lanes ``lanes`` by their chunk lists -- the one scan
         of all three flush paths.  The lanes are laid out as a lane
         bucket (``_bucket_rows``); ``scan.gather`` takes the bucket's
-        states out of the lane table (on each device, under ``mesh=``),
-        ``_scan_batch`` runs its segments, ``scan.scatter`` writes the
-        states back.  Pad lanes carry all-masked chunks, so their states
-        return bit-identical.  The gather and the scatter are waited on
-        and add into ``clock``.  Returns the scan width."""
+        states out of the lane table on each device, ``_scan_batch``
+        runs its segments, ``scan.scatter`` writes the states back.  Pad
+        lanes carry all-masked chunks, so their states return
+        bit-identical.  The gather and the scatter are waited on and add
+        into ``clock``.  Returns the scan width."""
         if not any(lane_chunks):
             return 0
         rows, idx, b = self._bucket_rows(lanes)
         pick = (lambda xs, pad: [pad if r < 0 else xs[r] for r in rows])
-        target = (None if self._sharded is None
-                  else self._sharded.lane_sharding)
         t0 = time.perf_counter_ns()
         with self.obs.span("scan.gather", cat="scan", scope=scope,
                            lanes=len(idx)):
-            idx = jax.device_put(idx, target)
-            sub = jax.block_until_ready(self._gather(self._states, idx))
+            idx = jax.device_put(idx, self._table.sharding)
+            sub = jax.block_until_ready(
+                self._table.gather(self._states, idx))
         gather_ns = time.perf_counter_ns() - t0
         sub, width = self._scan_batch(
             sub, pick(lane_chunks, []), pick(lane_masks, []),
@@ -888,25 +855,20 @@ class SessionEngine:
         t0 = time.perf_counter_ns()
         with self.obs.span("scan.scatter", cat="scan", scope=scope,
                            lanes=len(idx)):
-            self._update_table(self._scatter, idx, sub)
+            self._update_table(self._table.scatter, idx, sub)
             jax.block_until_ready(self._states)
         clock.lanes(gather_ns + time.perf_counter_ns() - t0, len(idx),
                     len(lanes))
         return width
 
     def _reset_group(self, lanes: List[int]) -> None:
-        """Reset lanes ``lanes`` to fresh state: one batched lane-init
-        per ``lanes_per_device`` lanes, its idx padded up to a lane
-        bucket by repeating a lane (resetting a lane twice is a no-op),
-        so the reset shapes are the lane buckets."""
-        cap = self._lane_buckets[-1]
-        for lo in range(0, len(lanes), cap):
-            part = lanes[lo:lo + cap]
-            part = part + [part[0]] * (self._lane_bucket(len(part))
-                                       - len(part))
-            self._update_table(self._reset_lanes, np.asarray(part, np.int32))
-            if self._sharded is not None:
-                self._states = self._sharded.shard_states(self._states)
+        """Reset the distinct lanes ``lanes`` to fresh state in one
+        batched lane-init: each device resets its own, its ids padded
+        with ``-1`` up to a lane bucket, so the reset shapes are the
+        lane buckets."""
+        _, idx, _ = self._bucket_rows(lanes, fill=False)
+        self._update_table(self._table.reset,
+                           jax.device_put(idx, self._table.sharding))
 
     def _update_table(self, program, *args) -> None:
         """Run a lane-table update ``program(states, *args)`` (the
@@ -924,17 +886,15 @@ class SessionEngine:
 
         * ``scan.pack``: ``_pack_chunks``, the dense host batch;
         * ``scan.h2d``: the batch copied to the device (split over the
-          lanes axis under ``mesh=``) and waited on;
+          lanes axis) and waited on;
         * ``scan.run``: the compiled scan plus the wait for its stats
           (``_apply_exec_stats``) -- device time as this thread sees it.
 
         ``(bucket, width)`` looks the executable up in the AOT table;
-        ``self._run`` is the jit fallback.  The step times add into
-        ``clock`` whether tracing is on or off.  Returns
+        the lane table's ``scan`` is the jit fallback.  The step times
+        add into ``clock`` whether tracing is on or off.  Returns
         ``(state, width)``."""
         span = self.obs.span
-        target = (None if self._sharded is None
-                  else self._sharded.lane_sharding)
         width = 0
         for off, w in self._segments(lane_chunks):
             with span("scan.segment", cat="scan", scope=scope, offset=off,
@@ -946,11 +906,11 @@ class SessionEngine:
                 t1 = time.perf_counter_ns()
                 with span("scan.h2d", cat="scan"):
                     chunks, mask = jax.block_until_ready(
-                        jax.device_put((chunks, mask), target))
+                        jax.device_put((chunks, mask), self._table.sharding))
                 t2 = time.perf_counter_ns()
                 with span("scan.run", cat="scan"):
-                    state, stats = self._aot.get((bucket, w), self._run)(
-                        state, chunks, mask)
+                    state, stats = self._aot.get(
+                        (bucket, w), self._table.scan)(state, chunks, mask)
                     self._apply_exec_stats(
                         stats, row_sessions,
                         [min(max(len(c) - off, 0), w) for c in lane_chunks])
@@ -987,8 +947,8 @@ class SessionEngine:
 
         For every lane bucket (``_lane_buckets``: 1, 2, 4, ... lanes per
         device, up to ``lanes_per_device``) AOT-lowers and compiles one
-        scan executable per width (``jit(scan_lanes).lower().compile()``,
-        shard_map'd over the mesh when distributed) and primes the
+        scan executable per width (the lane table's ``scan``, lowered
+        and compiled) and primes the
         bucket's gather, scatter and lane reset by executing them on
         scratch states; then primes the remaining fixed-shape entry
         points (merge, fold, the secondary scheduler) -- so ``flush`` /
@@ -1025,40 +985,33 @@ class SessionEngine:
         t0 = time.perf_counter()
         before = compilemon.snapshot()
         c, feat = self.chunk_size, self._feat_shape
-        scratch = (self._sharded.init_states() if self._sharded is not None
-                   else core_executor.stack_states(self._fresh,
-                                                   self.num_lanes))
-        target = (None if self._sharded is None
-                  else self._sharded.lane_sharding)
-        n_dev = self.num_lanes // self.lanes_per_device
+        table = self._table
+        scratch = table.init_states()
+        n_dev = table.num_devices
         in_place: List[bool] = []       # per lane-table update program
         for b in self._lane_buckets:
             idx = jax.device_put(np.tile(np.arange(b, dtype=np.int32), n_dev),
-                                 target)
-            sub = self._gather(scratch, idx)
+                                 table.sharding)
+            sub = table.gather(scratch, idx)
             for w in self._aot_widths:
                 zc = jax.ShapeDtypeStruct((n_dev * b, w, c, *feat),
-                                          self._dtype, sharding=target)
+                                          self._dtype, sharding=table.sharding)
                 zm = jax.ShapeDtypeStruct((n_dev * b, w, c), np.bool_,
-                                          sharding=target)
-                self._aot[(b, w)] = self._run.lower(sub, zc, zm).compile()
+                                          sharding=table.sharding)
+                self._aot[(b, w)] = table.scan.lower(sub, zc, zm).compile()
             # the update programs take ``scratch`` donated: rebind it
-            scratch = self._scatter(scratch, idx, sub)
-            in_place.append(self._aliases_table(self._scatter, scratch,
+            scratch = table.scatter(scratch, idx, sub)
+            in_place.append(self._aliases_table(table.scatter, scratch,
                                                 idx, sub))
-            part = np.arange(b, dtype=np.int32)
-            scratch = self._reset_lanes(scratch, part)
-            in_place.append(self._aliases_table(self._reset_lanes, scratch,
-                                                part))
-            if self._sharded is not None:
-                scratch = self._sharded.shard_states(scratch)
+            scratch = table.reset(scratch, idx)
+            in_place.append(self._aliases_table(table.reset, scratch, idx))
         # remaining fixed-shape entry points (query/close/re-grant): a
         # plain execution populates their jit caches
-        self._merge_lane(scratch, 0)
+        table.snapshot(scratch, 0)
         if self.secondary_slots and self.spec.merge is None:
-            scratch = self._fold_lane(scratch, self.primary_slots, 0)
+            scratch = table.fold(scratch, self.primary_slots, 0)
             in_place.append(self._aliases_table(
-                self._fold_lane, scratch, self.primary_slots, 0))
+                table.fold, scratch, self.primary_slots, 0))
         self._res.merge_state(self._fresh)
         self.plan_secondary(np.zeros(self.primary_slots, np.float32))
         d = compilemon.since(before)
@@ -1277,8 +1230,8 @@ class SessionEngine:
             if old >= 0:
                 # the lifted §IV-B merge: shadow lane folds into its old
                 # session's primary lane before re-assignment
-                self._update_table(self._fold_lane, self.primary_slots + j,
-                                   old)
+                self._update_table(self._table.fold,
+                                   self.primary_slots + j, old)
                 self._slot_reschedules += 1
             self._sec_assign[j] = new[j]
             if self.obs.enabled and int(new[j]) >= 0:
@@ -1289,18 +1242,6 @@ class SessionEngine:
             summary = scheduler.plan_summary(backlog, new)
             self._mx.sched_granted.set(summary["n_granted"])
             self._mx.sched_load.set(summary["max_load_after"])
-
-    def _fold_lane_impl(self, states, src, dst):
-        contrib = self._res.merge_state(
-            jax.tree.map(lambda x: x[src], states))
-        bufs = states.buffers
-        if self.spec.combine == "add":
-            bufs = bufs.at[dst, :self.num_pri].add(contrib)
-        else:
-            bufs = bufs.at[dst, :self.num_pri].max(contrib)
-        states = dataclasses.replace(states, buffers=bufs)
-        return jax.tree.map(lambda x, f: x.at[src].set(f), states,
-                            self._fresh)
 
     # ------------------------------------------------------------- snapshots
 
@@ -1313,10 +1254,10 @@ class SessionEngine:
         with self.obs.span("merge.snapshot", cat="merge", sid=s.sid,
                            tenant=s.tenant):
             merged = jax.tree.map(np.asarray,
-                                  self._merge_lane(self._states, s.slot))
+                                  self._table.snapshot(self._states, s.slot))
             for j in range(self.secondary_slots):
                 if self._sec_assign[j] == s.slot:
-                    contrib = jax.tree.map(np.asarray, self._merge_lane(
+                    contrib = jax.tree.map(np.asarray, self._table.snapshot(
                         self._states, self.primary_slots + j))
                     combine = (np.add if self.spec.combine == "add"
                                else np.maximum)
@@ -1520,9 +1461,7 @@ class SessionEngine:
                     "chunk_size": self.chunk_size,
                     "primary_slots": self.primary_slots,
                     "secondary_slots": self.secondary_slots,
-                    "mesh_devices": (None if self._sharded is None
-                                     else self.num_lanes
-                                     // self.lanes_per_device),
+                    "mesh_devices": self._table.num_devices,
                     "lanes_per_device": self.lanes_per_device,
                     "aot_buckets": (None if self._aot_widths is None
                                     else int(self._aot_widths[-1])),

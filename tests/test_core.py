@@ -405,29 +405,27 @@ class TestLanePrimitives:
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
     def test_take_put_roundtrip_on_mesh_of_1(self):
-        """The same round-trip through a SHARDED lanes stack: gather off
-        the mesh, scatter back, re-pin to the lane sharding -- the
-        distributed per-session flush and checkpoint-restore path."""
+        """The same round-trip through the lane table on a one-device
+        mesh (``core.distributed.make_lane_table``): scan, gather a
+        permuted subset, scatter it back -- the per-session flush and
+        checkpoint-restore path.  Every lane's snapshot then equals a
+        one-shot ``make_executor`` run over that lane's chunks."""
         from repro.core import distributed as D
         E, res = self._setup()
-        mesh = jax.make_mesh((1,), ("lanes",))
-        sh = D.make_lane_sharded_executor(res, mesh, self.L)
-        states = sh.init_states()
+        table = D.make_lane_table(res, jax.make_mesh((1,), ("lanes",)),
+                                  self.L)
         rng = np.random.default_rng(5)
         keys = rng.integers(0, self.M * 16, size=(self.L, 2, self.C))
         chunks = jnp.asarray(np.stack([keys, keys], axis=-1), jnp.int32)
-        states, _ = sh.run_lanes(states, chunks,
-                                 jnp.ones((self.L, 2, self.C), bool))
-        idx = jnp.asarray([2, 0], jnp.int32)
-        sub = E.take_lanes(states, idx)
-        back = sh.shard_states(E.put_lanes(states, idx, sub))
-        for got, want in zip(jax.tree.leaves(back),
-                             jax.tree.leaves(states)):
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-        for ln in range(self.L):          # merged answers survive the trip
+        states, _ = table.scan(table.init_states(), chunks,
+                               jnp.ones((self.L, 2, self.C), bool))
+        idx = jax.device_put(np.asarray([2, 0], np.int32), table.sharding)
+        back = table.scatter(states, idx, table.gather(states, idx))
+        run = E.make_executor(res.spec, self.M, self.X, self.C)
+        for ln in range(self.L):
+            want, _ = run(chunks[ln])
             np.testing.assert_array_equal(
-                np.asarray(sh.merge_lane(back, ln)),
-                np.asarray(sh.merge_lane(states, ln)))
+                np.asarray(table.snapshot(back, ln)), np.asarray(want))
 
 
 # ------------------------------------------------------- input pipeline

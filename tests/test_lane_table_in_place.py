@@ -62,18 +62,17 @@ def check_in_place(app: str, mesh=None) -> dict:
     info = eng.warmup(dtype=np.int32, feat_shape=(2,))
     m = 2 * len(eng._lane_buckets) + 1       # scatter + reset per bucket, fold
     assert info["table_in_place"] == f"{m} of {m}", info
-    states = eng._states
+    states, table = eng._states, eng._table
     n_leaves = len(jax.tree.leaves(states))
     buffers = (eng.lanes_per_device, *states.buffers.shape[1:])
-    n_dev = eng.num_lanes // eng.lanes_per_device
     b = eng._lane_buckets[-1]
-    target = None if eng._sharded is None else eng._sharded.lane_sharding
-    idx = jax.device_put(np.tile(np.arange(b, dtype=np.int32), n_dev),
-                         target)
+    idx = jax.device_put(
+        np.tile(np.arange(b, dtype=np.int32), table.num_devices),
+        table.sharding)
     programs = {
-        "scatter": (eng._scatter, (idx, eng._gather(states, idx))),
-        "reset": (eng._reset_lanes, (np.arange(b, dtype=np.int32),)),
-        "fold": (eng._fold_lane, (eng.primary_slots, 0)),
+        "scatter": (table.scatter, (idx, table.gather(states, idx))),
+        "reset": (table.reset, (idx,)),
+        "fold": (table.fold, (eng.primary_slots, 0)),
     }
     for name, (program, args) in programs.items():
         text = program.lower(states, *args).compile().as_text()

@@ -4,9 +4,9 @@ Drives random interleavings of ``open`` / ``open_batch`` / ``append`` /
 ``query`` / ``close`` / ``flush`` / ``flush_session`` / ``recover``
 against TWO implementations in lockstep:
 
-  * the real ``serve.SessionEngine`` (local, mesh-of-1, and durable
-    variants -- the mesh-of-1 engine must be bit-exact vs local, and a
-    recovered durable engine must be bit-exact vs never having crashed);
+  * the real ``serve.SessionEngine`` (on the default device, on a
+    caller's one-device mesh, and durable -- a recovered durable engine
+    must be bit-exact vs never having crashed);
   * ``OracleModel``, a pure-numpy model of the documented semantics --
     FIFO waitlist into the lowest free slot, chunk-granular engine-wide
     flushes, everything-through per-session flushes, ``ValueError`` for

@@ -17,9 +17,7 @@ worker imports this file.
 """
 from __future__ import annotations
 
-import functools
 import re
-import types
 
 import jax
 import jax.numpy as jnp
@@ -102,33 +100,31 @@ def test_histo_lane_scan_compiles(one_chip):
     assert used < V5E_HBM
 
 
-def test_hll_lane_bucket_compiles(one_chip):
+def _one_chip_mesh(topo):
+    return Mesh(np.array(topo.devices[:1]), ("lanes",))
+
+
+def test_hll_lane_bucket_compiles(topo):
     """One engine-wide flush of the hll-16k deployment, the way the
     active-lane flush runs it: an 8-lane bucket gathered out of the
     16,448-lane table of p = 14 registers, scanned with the native
-    ``max`` kernel, scattered back -- each program within one chip."""
+    ``max`` kernel, scattered back -- the lane table's programs on one
+    chip, each within its memory."""
     lanes, bucket = 16448, 8
     spec = hll.make_spec(14, 16)
     res = core_executor.make_resumable_executor(
         spec, 16, 4, T, kernel_backend=dispatch.PALLAS)
-
-    def states(n):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip),
-            jax.eval_shape(lambda: core_executor.stack_states(
-                res.init_state(), n)))
-
-    table, sub = states(lanes), states(bucket)
-    idx = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=one_chip)
-    chunks = jax.ShapeDtypeStruct((bucket, 1, T, 2), jnp.int32,
-                                  sharding=one_chip)
-    mask = jax.ShapeDtypeStruct((bucket, 1, T), jnp.bool_,
-                                sharding=one_chip)
-    compiled = [
-        jax.jit(core_executor.take_lanes).lower(table, idx).compile(),
-        _compile(res.scan_lanes, sub, chunks, mask),
-        jax.jit(core_executor.put_lanes).lower(table, idx, sub).compile()]
+    table = core_distributed.make_lane_table(res, _one_chip_mesh(topo),
+                                             lanes)
+    on = table.sharding
+    full, sub = _table(res, lanes, on), _table(res, bucket, on)
+    idx = jax.ShapeDtypeStruct((bucket,), jnp.int32, sharding=on)
+    chunks = jax.ShapeDtypeStruct((bucket, 1, T, 2), jnp.int32, sharding=on)
+    mask = jax.ShapeDtypeStruct((bucket, 1, T), jnp.bool_, sharding=on)
+    compiled = [table.gather.lower(full, idx).compile(),
+                table.scan.lower(sub, chunks, mask).compile(),
+                table.scatter.lower(full, idx, sub).compile()]
+    assert "tpu_custom_call" in compiled[1].as_text()
     for c in compiled:
         mem = c.memory_analysis()
         assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -163,63 +159,45 @@ def _assert_in_place(compiled, buffers_shape, table_bytes):
     assert mem.temp_size_in_bytes < table_bytes // 100
 
 
-@pytest.mark.parametrize("deployment", sorted(DEPLOYMENTS))
-def test_lane_table_updates_in_place(one_chip, deployment):
-    """``SessionEngine``'s scatter (``executor.put_lanes``), group reset
-    (``executor.reset_lanes``) and secondary fold, donated as the engine
-    binds them, write the 1.36-1.43-GB table of each deployment in place
-    on one chip: an 8-lane bucket, not a copy of the table."""
-    make_spec, lanes = DEPLOYMENTS[deployment]
-    spec = make_spec()
+def _check_updates_in_place(make_spec, lanes, mesh):
+    """The lane table's scatter, reset and fold of an 8-lane-per-device
+    bucket, donated as built, each write every device's share of the
+    table in place, with no collective but the fold's psum."""
     res = core_executor.make_resumable_executor(
-        spec, 16, 4, T, kernel_backend=dispatch.PALLAS)
-    table = _table(res, lanes, one_chip)
-    sub = _table(res, 8, one_chip)
-    idx = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
-    lane = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    fresh = res.init_state()
-    engine = types.SimpleNamespace(_res=res, spec=spec, num_pri=16,
-                                   _fresh=fresh)
-    from repro.serve import SessionEngine
-    fold = functools.partial(SessionEngine._fold_lane_impl, engine)
-    table_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                      for x in jax.tree.leaves(table))
-    donated = functools.partial(jax.jit, donate_argnums=0)
-    for fn, args in (
-            (core_executor.put_lanes, (idx, sub)),
-            (lambda s, i: core_executor.reset_lanes(s, i, fresh), (idx,)),
-            (fold, (lane, lane))):
-        _assert_in_place(donated(fn).lower(table, *args).compile(),
-                         table.buffers.shape, table_bytes)
+        make_spec(), 16, 4, T, kernel_backend=dispatch.PALLAS)
+    table = core_distributed.make_lane_table(res, mesh, lanes)
+    n_dev = table.num_devices
+    replicated = NamedSharding(table.mesh, PartitionSpec())
+    full = _table(res, lanes, table.sharding)
+    sub = _table(res, 8 * n_dev, table.sharding)
+    idx = jax.ShapeDtypeStruct((8 * n_dev,), jnp.int32,
+                               sharding=table.sharding)
+    lane = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)
+    local_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree.leaves(full)) // n_dev
+    local_buffers = (lanes // n_dev, *full.buffers.shape[1:])
+    for program, args in ((table.scatter, (idx, sub)),
+                          (table.reset, (idx,)),
+                          (table.fold, (lane, lane))):
+        compiled = program.lower(full, *args).compile()
+        assert "all-gather" not in compiled.as_text()
+        _assert_in_place(compiled, local_buffers, local_bytes)
+
+
+@pytest.mark.parametrize("deployment", sorted(DEPLOYMENTS))
+def test_lane_table_updates_in_place(topo, deployment):
+    """The engine's scatter, group reset and secondary fold write the
+    1.36-1.43-GB table of each deployment in place on one chip: an
+    8-lane bucket, not a copy of the table."""
+    make_spec, lanes = DEPLOYMENTS[deployment]
+    _check_updates_in_place(make_spec, lanes, _one_chip_mesh(topo))
 
 
 def test_lane_table_updates_in_place_mesh(topo):
-    """The same on the 2x2 mesh of the four-chip engine: the
-    ``ShardedLaneExecutor``'s ``put_local`` and ``fold_lane`` and the
-    engine's lane-sharded group reset, each donated, write each chip's
-    quarter of the histo-1k table in place."""
-    spec = histo.make_spec(262144, 1 << 22, 16)
-    res = core_executor.make_resumable_executor(
-        spec, 16, 4, T, kernel_backend=dispatch.PALLAS)
-    lanes, n_dev = 1088, 4
-    mesh = Mesh(np.array(topo.devices[:n_dev]), ("lanes",))
-    sh = core_distributed.make_lane_sharded_executor(res, mesh, lanes)
-    replicated = NamedSharding(sh.mesh, PartitionSpec())
-    table = _table(res, lanes, sh.lane_sharding)
-    sub = _table(res, 8 * n_dev, sh.lane_sharding)
-    idx = jax.ShapeDtypeStruct((8 * n_dev,), jnp.int32,
-                               sharding=sh.lane_sharding)
-    group = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=replicated)
-    lane = jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)
-    fresh = res.init_state()
-    local_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                      for x in jax.tree.leaves(table)) // n_dev
-    local_buffers = (lanes // n_dev, *table.buffers.shape[1:])
-    donated = functools.partial(jax.jit, donate_argnums=0)
-    for fn, args in (
-            (sh.put_local, (idx, sub)),
-            (sh.fold_lane, (lane, lane)),
-            (lambda s, i: core_executor.reset_lanes(s, i, fresh), (group,))):
-        compiled = donated(fn).lower(table, *args).compile()
-        assert "all-gather" not in compiled.as_text()
-        _assert_in_place(compiled, local_buffers, local_bytes)
+    """The same on the 2x2 mesh of the four-chip engine: the same
+    programs write each chip's quarter of the histo-1k table in
+    place."""
+    make_spec, lanes = DEPLOYMENTS["histo-1k"]
+    _check_updates_in_place(make_spec, lanes,
+                            Mesh(np.array(topo.devices[:4]), ("lanes",)))
+
